@@ -21,13 +21,14 @@ f() — bit-identical grouping to cadc_conv2d.
 
 Grid: (B, OH/bh, Cout/bn), all parallel — the segment loop runs INSIDE the
 kernel body over a VMEM scratch accumulator (no S grid axis, no O(S)
-pl.when dispatch chain, no output revisits). x block = one padded image
-[1, HP, WP, C]; w block = [D, bn] column slice; out block = [1, bh, OW, bn]
-written exactly once.
+pl.when dispatch chain, no output revisits). x block = the stride phases
+of one padded image [1, s1*s2, HQ, WQ, C]; w block = [D, bn] column slice;
+out block = [1, bh, OW, bn] written exactly once.
 
-Constraints: dilation=1; stride via in-register slicing; the padded image
-must fit VMEM (wrapper falls back to the im2col XLA path otherwise — see
-ops.cadc_conv2d).
+Constraints: dilation=1; strides are split into input phases outside the
+kernel (_stride_phases) so every in-kernel read is unit-stride; the padded
+image must fit VMEM (wrapper falls back to the im2col XLA path otherwise —
+see ops.cadc_conv2d).
 
 Quantized variant (cadc_conv2d_q8_pallas)
 -----------------------------------------
@@ -73,10 +74,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import dendritic
 from repro.core.conv import _norm_padding, im2col
-from repro.kernels.cadc_matmul import (GATE_PACK_WIDTH, CompilerParams,
-                                       _float0_zeros, _pack_mask,
-                                       _resolve_gate, _resolve_gate_mode,
-                                       _segmented_bwd)
+from repro.kernels.cadc_matmul import (GATE_PACK_WIDTH, _float0_zeros,
+                                       _pack_mask, _resolve_gate,
+                                       _resolve_gate_mode, _segmented_bwd)
 
 Array = jnp.ndarray
 
@@ -103,26 +103,26 @@ def _segment_taps(k1: int, k2: int, c: int, xbar: int):
 def _tap_psum(x_ref, w_ref, taps, *, oh0, bh, ow, s1, s2, xbar, bn, si,
               acc_dtype=jnp.float32):
     """Accumulate one segment's psum tile [bh*ow, bn] over its taps.
-    acc_dtype=int32 gives the exact integer psums of the q8 path."""
+
+    x_ref holds the stride phases of the padded image (_stride_phases):
+    tap (i, j) of output pixel (r, q) lives in phase (i % s1, j % s2) at
+    (r + i // s1, q + j // s2), so every read is unit-stride — Mosaic
+    refuses strided loads of sub-32-bit data and gathers from a loaded
+    value. acc_dtype=int32 gives the exact integer psums of the q8 path
+    (int8 operands straight into the MXU)."""
     p = jnp.zeros((bh * ow, bn), acc_dtype)
     for (i, j, c_lo, c_sz, d_off) in taps:
-        rows = (bh - 1) * s1 + 1
-        cols = (ow - 1) * s2 + 1
-        xt = pl.load(
-            x_ref,
-            (pl.ds(0, 1), pl.ds(oh0 + i, rows), pl.ds(j, cols),
-             pl.ds(c_lo, c_sz)),
-        )[0]  # [rows, cols, c_sz]
-        xt = xt[::s1, ::s2, :].reshape(bh * ow, c_sz)
+        xt = x_ref[0, (i % s1) * s2 + j % s2, pl.ds(oh0 + i // s1, bh),
+                   j // s2:j // s2 + ow, c_lo:c_lo + c_sz]
         wt = w_ref[si * xbar + d_off : si * xbar + d_off + c_sz, :]
-        p += jnp.dot(xt.astype(acc_dtype), wt.astype(acc_dtype),
+        p += jnp.dot(xt.reshape(bh * ow, c_sz), wt,
                      preferred_element_type=acc_dtype)
     return p
 
 
 def _kernel(x_ref, w_ref, o_ref, acc_ref, *, fn: Callable, segs, bh: int,
             ow: int, s1: int, s2: int, xbar: int, bn: int):
-    oh0 = pl.program_id(1) * bh * s1  # first input row of this row block
+    oh0 = pl.program_id(1) * bh  # first phase row of this row block
     for si, taps in enumerate(segs):
         p = _tap_psum(x_ref, w_ref, taps, oh0=oh0, bh=bh, ow=ow, s1=s1,
                       s2=s2, xbar=xbar, bn=bn, si=si)
@@ -138,7 +138,7 @@ def _kernel_with_gate(x_ref, w_ref, o_ref, g_ref, acc_ref, *, fn: Callable,
                       gate_fn: Callable, segs, bh: int, ow: int, s1: int,
                       s2: int, xbar: int, bn: int, packed: bool):
     """VJP forward: also writes each segment's gate f'(psum) tile."""
-    oh0 = pl.program_id(1) * bh * s1
+    oh0 = pl.program_id(1) * bh
     for si, taps in enumerate(segs):
         p = _tap_psum(x_ref, w_ref, taps, oh0=oh0, bh=bh, ow=ow, s1=s1,
                       s2=s2, xbar=xbar, bn=bn, si=si)
@@ -160,7 +160,7 @@ def _q8_kernel(x_ref, w_ref, scale_ref, o_ref, acc_ref, *, fn: Callable,
                segs, bh: int, ow: int, s1: int, s2: int, xbar: int, bn: int):
     """int8 taps x int8 ternary codes -> int32 segment psum -> dequant ->
     f() -> fp32 accumulate. scale_ref is (1,1) fp32."""
-    oh0 = pl.program_id(1) * bh * s1
+    oh0 = pl.program_id(1) * bh
     for si, taps in enumerate(segs):
         p_i32 = _tap_psum(x_ref, w_ref, taps, oh0=oh0, bh=bh, ow=ow, s1=s1,
                           s2=s2, xbar=xbar, bn=bn, si=si,
@@ -177,7 +177,7 @@ def _q8_kernel_with_gate(x_ref, w_ref, scale_ref, o_ref, g_ref, acc_ref, *,
                          fn: Callable, gate_fn: Callable, segs, bh: int,
                          ow: int, s1: int, s2: int, xbar: int, bn: int,
                          packed: bool):
-    oh0 = pl.program_id(1) * bh * s1
+    oh0 = pl.program_id(1) * bh
     for si, taps in enumerate(segs):
         p_i32 = _tap_psum(x_ref, w_ref, taps, oh0=oh0, bh=bh, ow=ow, s1=s1,
                           s2=s2, xbar=xbar, bn=bn, si=si,
@@ -223,6 +223,21 @@ def _col2im(
     return dx[:, pt : pt + h, pl_ : pl_ + w, :]
 
 
+def _stride_phases(x, pad_h, pad_w, stride, hq, wq):
+    """Zero-pad x [B, H, W, C] and split it into its s1*s2 stride phases
+    [B, s1*s2, hq, wq, C]: phase a*s2 + b holds padded[:, a::s1, b::s2].
+    hq/wq cover every row/col a tap reads. For stride 1 this is the padded
+    image with a unit phase axis."""
+    s1, s2 = stride
+    b, h, w, c = x.shape
+    # lax.pad: a negative high pad crops rows/cols no tap reads
+    xp = jax.lax.pad(x, jnp.zeros((), x.dtype),
+                     ((0, 0, 0), (pad_h[0], hq * s1 - h - pad_h[0], 0),
+                      (pad_w[0], wq * s2 - w - pad_w[0], 0), (0, 0, 0)))
+    xp = xp.reshape(b, hq, s1, wq, s2, c).transpose(0, 2, 4, 1, 3, 5)
+    return xp.reshape(b, s1 * s2, hq, wq, c)
+
+
 def _conv_pallas(x, w, *, f, gate_fn, gate_dt, gate_mode, crossbar_size,
                  stride, padding, block_h, block_n, interpret, scale2=None):
     """Run the fused conv (optionally emitting the gate) — returns
@@ -232,21 +247,26 @@ def _conv_pallas(x, w, *, f, gate_fn, gate_dt, gate_mode, crossbar_size,
     k1, k2, cin, cout = w.shape
     s1, s2 = stride
     (pt, pb), (pl_, pr) = _norm_padding(padding, (k1, k2), (1, 1))
-    xp = jnp.pad(x, ((0, 0), (pt, pb), (pl_, pr), (0, 0)))
-    b, hp, wp, _ = xp.shape
-    oh = (hp - k1) // s1 + 1
-    ow = (wp - k2) // s2 + 1
+    b, h, wd, _ = x.shape
+    oh = (h + pt + pb - k1) // s1 + 1
+    ow = (wd + pl_ + pr - k2) // s2 + 1
 
     bh = min(block_h, oh)
-    # pad OH to a multiple of bh (extra input rows so the last block reads
-    # in-bounds; results sliced off)
+    # OH padded to a multiple of bh (the last block reads extra zero rows;
+    # results sliced off)
     oh_pad = -(-oh // bh) * bh
-    extra_rows = (oh_pad - 1) * s1 + k1 - hp
-    if extra_rows > 0:
-        xp = jnp.pad(xp, ((0, 0), (0, extra_rows), (0, 0), (0, 0)))
-        hp = xp.shape[1]
     bn = min(block_n, cout)
     cout_pad = -(-cout // bn) * bn
+    quantized = scale2 is not None
+    if quantized:
+        # int8 straight into the MXU; float primals of the STE path hold
+        # the same integer codes
+        x, w = x.astype(jnp.int8), w.astype(jnp.int8)
+    else:
+        dt = jnp.result_type(x.dtype, w.dtype)
+        x, w = x.astype(dt), w.astype(dt)
+    xph = _stride_phases(x, (pt, pb), (pl_, pr), stride,
+                         oh_pad + (k1 - 1) // s1, ow + (k2 - 1) // s2)
     w2d = w.reshape(k1 * k2 * cin, cout)
     if cout_pad != cout:
         w2d = jnp.pad(w2d, ((0, 0), (0, cout_pad - cout)))
@@ -257,18 +277,15 @@ def _conv_pallas(x, w, *, f, gate_fn, gate_dt, gate_mode, crossbar_size,
     kw = dict(segs=segs, bh=bh, ow=ow, s1=s1, s2=s2, xbar=crossbar_size,
               bn=bn)
     with_gate = gate_mode in ("packed", "bytes")
-    quantized = scale2 is not None
 
     in_specs = [
-        pl.BlockSpec((1, hp, wp, cin), lambda bi, hi, ni: (bi, 0, 0, 0)),
+        pl.BlockSpec((1,) + xph.shape[1:],
+                     lambda bi, hi, ni: (bi, 0, 0, 0, 0)),
         pl.BlockSpec((k1 * k2 * cin, bn), lambda bi, hi, ni: (0, ni)),
     ]
-    operands = [xp, w2d]
+    operands = [xph, w2d]
     if quantized:
-        in_specs.append(
-            pl.BlockSpec((1, 1), lambda bi, hi, ni: (0, 0),
-                         memory_space=pl.ANY)
-        )
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         operands.append(scale2)
     out_specs = pl.BlockSpec(
         (1, bh, ow, bn), lambda bi, hi, ni: (bi, hi, 0, ni)
@@ -302,7 +319,7 @@ def _conv_pallas(x, w, *, f, gate_fn, gate_dt, gate_mode, crossbar_size,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bh * ow, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")
         ),
         interpret=interpret,

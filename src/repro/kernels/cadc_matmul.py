@@ -87,9 +87,6 @@ from repro.core import dendritic
 
 Array = jnp.ndarray
 
-# jax 0.4.x exposes TPUCompilerParams; newer versions renamed it.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 # Gate bits per packed residual word (uint32 lane packing along N).
 GATE_PACK_WIDTH = 32
 
@@ -149,8 +146,8 @@ def _seg_psum(x_ref, w_ref, s: int, xbar: int) -> Array:
 
 def _seg_psum_q8(x_ref, w_ref, scale_ref, s: int, xbar: int) -> Array:
     psum_i32 = jnp.dot(
-        x_ref[:, s * xbar:(s + 1) * xbar].astype(jnp.int32),
-        w_ref[s * xbar:(s + 1) * xbar, :].astype(jnp.int32),
+        x_ref[:, s * xbar:(s + 1) * xbar],
+        w_ref[s * xbar:(s + 1) * xbar, :],
         preferred_element_type=jnp.int32,
     )
     return psum_i32.astype(jnp.float32) * scale_ref[0, 0]
@@ -391,7 +388,8 @@ def _fit_axis(x: Array, axis: int, size: int) -> Array:
 
 
 def _dim_sem(n: int = 3):
-    return CompilerParams(dimension_semantics=("parallel",) * (n - 1) + ("arbitrary",))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (n - 1) + ("arbitrary",))
 
 
 def _auto_d_chunk(dp: int, bm: int, bn: int, itemsize: int, xbar: int,
@@ -443,9 +441,7 @@ def _fwd_pallas(xp, wp, *, f, gate_fn, gate_mode, gate_dt, xbar, bm, bn,
         ]
     operands = [xp, wp]
     if quantized:
-        in_specs.append(pl.BlockSpec(
-            (1, 1), (lambda i, j, c: (0, 0)) if chunked
-            else (lambda i, j: (0, 0)), memory_space=pl.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         operands.append(scale2)
 
     out_specs = pl.BlockSpec(
@@ -481,7 +477,7 @@ def _fwd_pallas(xp, wp, *, f, gate_fn, gate_mode, gate_dt, xbar, bm, bn,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
             [: len(grid)]
         ),
@@ -542,18 +538,17 @@ def _segmented_bwd(
                   else jnp.asarray(scale, jnp.float32).reshape(1, 1))
         dx_body = functools.partial(_bwd_dx_kernel_recompute, gate_fn=gate_fn)
         dw_body = functools.partial(_bwd_dw_kernel_recompute, gate_fn=gate_fn)
-        scale_spec = lambda ix: pl.BlockSpec((1, 1), ix, memory_space=pl.ANY)
         dx_specs = [
             pl.BlockSpec((block_m, block_n), lambda i, s, k: (i, k)),
             pl.BlockSpec((block_m, crossbar_size), lambda i, s, k: (i, s)),
             pl.BlockSpec((crossbar_size, block_n), lambda i, s, k: (s, k)),
-            scale_spec(lambda i, s, k: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ]
         dw_specs = [
             pl.BlockSpec((block_m, crossbar_size), lambda s, j, k: (k, s)),
             pl.BlockSpec((block_m, block_n), lambda s, j, k: (k, j)),
             pl.BlockSpec((crossbar_size, block_n), lambda s, j, k: (s, j)),
-            scale_spec(lambda s, j, k: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ]
         args_dx = [gp, xp, wp, scale2]
         args_dw = [xp, gp, wp, scale2]
@@ -795,12 +790,16 @@ def _diff_matmul_q8_op(crossbar_size: int, fn: str, block_m: int, block_n: int,
     def _run(x2, w, scale, gate_mode):
         m, d = x2.shape
         n = w.shape[1]
-        xp = _pad_to(_pad_to(x2, 1, crossbar_size), 0, block_m)
-        wp = _pad_to(_pad_to(w, 0, crossbar_size), 1, block_n)
+        # int8 straight into the MXU; float primals of the STE path hold
+        # the same integer codes
+        xp = _pad_to(_pad_to(x2.astype(jnp.int8), 1, crossbar_size), 0,
+                     block_m)
+        wp = _pad_to(_pad_to(w.astype(jnp.int8), 0, crossbar_size), 1,
+                     block_n)
         scale2 = scale.reshape(1, 1).astype(jnp.float32)
         d_chunk = _auto_d_chunk(
             xp.shape[1], block_m, block_n,
-            max(jnp.dtype(x2.dtype).itemsize, jnp.dtype(w.dtype).itemsize),
+            1,  # int8 strips
             crossbar_size,
             _gate_block_bytes(gate_mode, gate_dt, block_m, block_n),
             vmem_budget_bytes,

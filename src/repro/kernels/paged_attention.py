@@ -70,9 +70,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jnp.ndarray
 
-# jax 0.4.x exposes TPUCompilerParams; newer versions renamed it.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 # THE masking value of the attention stack (models/lm/attention.py imports
 # it from here): finite, so masked scores underflow to exact-0 softmax
 # weight instead of producing NaNs on all-masked (idle-slot) rows. The
@@ -295,7 +292,7 @@ def paged_attention_pallas(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, k_, qg, hd), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -10,10 +10,20 @@ from typing import Tuple
 import jax
 
 
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices=None) -> jax.sharding.Mesh:
+    """jax.make_mesh with Auto axes: the model code places activations
+    through sharding constraints and GSPMD propagation, which Explicit axes
+    (the make_mesh default) turn into type errors."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

@@ -31,6 +31,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, smoke_config
+from repro.launch import compile_cache
 from repro.launch.train import make_local_mesh
 from repro.models.lm import transformer as tf
 from repro.serve import EngineConfig, ServeEngine, poisson_workload
@@ -76,6 +77,7 @@ def main(argv=None):
                     "n-gram (model-free) or a shrunk-config draft model")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = (smoke_config if args.smoke else get_config)(args.arch)
     if args.cadc:

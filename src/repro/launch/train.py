@@ -31,7 +31,7 @@ import json
 import os
 import signal
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,14 +40,52 @@ import numpy as np
 from repro.ckpt import checkpoint as ckpt
 from repro.configs import SHAPES, get_config, smoke_config
 from repro.data import synthetic
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
 from repro.launch import steps as steps_lib
 from repro.parallel import sharding as shard_lib
 
 
-def make_local_mesh() -> jax.sharding.Mesh:
-    n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+def make_local_mesh(devices=None) -> jax.sharding.Mesh:
+    """(n, 1) data mesh over `devices` (default: every visible device)."""
+    devices = jax.devices() if devices is None else list(devices)
+    return mesh_lib.make_mesh((len(devices), 1), ("data", "model"),
+                              devices=devices)
+
+
+class TrainState(NamedTuple):
+    params: Any
+    opt_state: Any
+    step_fn: Callable   # (params, opt_state, batch, step) -> (..., metrics)
+    pshard: Any         # NamedSharding pytrees on the mesh
+    oshard: Any
+    bshard: Any
+
+
+def init_train_state(cfg, mesh: jax.sharding.Mesh, *, n_micro: int = 1,
+                     seed: int = 0) -> TrainState:
+    """Params and optimizer state initialized under the mesh's sharding
+    rules, and the jitted train step. The step's outputs are pinned to the
+    same shardings as its inputs, so step t+1 reuses step t's executable
+    (left to propagation, the optimizer state came back re-laid and the
+    second step compiled again)."""
+    optimizer = steps_lib.make_optimizer(cfg)
+    train_step = steps_lib.make_train_step(cfg, optimizer, n_micro=n_micro)
+    params_shape = steps_lib.abstract_params(cfg)
+    pshard = shard_lib.to_named(
+        shard_lib.param_specs(params_shape, cfg, mesh), mesh)
+    # optimizer state = param-shaped moment trees ({'m', 'v'} / {'mom'})
+    oshard = {k: pshard
+              for k in jax.eval_shape(optimizer.init, params_shape)}
+    bshard = shard_lib.to_named(shard_lib.batch_specs(cfg, mesh, "train"),
+                                mesh)
+    with mesh:
+        params = jax.jit(lambda k: steps_lib.tf.init(k, cfg),
+                         out_shardings=pshard)(jax.random.PRNGKey(seed))
+        opt_state = jax.jit(optimizer.init, out_shardings=oshard)(params)
+    step_fn = jax.jit(train_step, donate_argnums=(0, 1),
+                      out_shardings=(pshard, oshard, None))
+    return TrainState(params, opt_state, step_fn, pshard, oshard, bshard)
 
 
 class StepWatchdog:
@@ -96,6 +134,7 @@ def main(argv=None) -> Dict[str, Any]:
     ap.add_argument("--production-mesh", action="store_true",
                     help="use the 16x16 pod mesh (needs 256 devices)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = (smoke_config if args.smoke else get_config)(args.arch)
     cfg = cfg.with_overrides(n_microbatches=args.microbatch)
@@ -109,24 +148,11 @@ def main(argv=None) -> Dict[str, Any]:
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"arch={cfg.name} cadc={args.cadc} params=...", flush=True)
 
-    optimizer = steps_lib.make_optimizer(cfg)
-    train_step = steps_lib.make_train_step(cfg, optimizer,
-                                           n_micro=args.microbatch)
-
-    # init (or restore) under the mesh's sharding rules
-    params_shape = steps_lib.abstract_params(cfg)
-    pspecs = shard_lib.param_specs(params_shape, cfg, mesh)
-    pshard = shard_lib.to_named(pspecs, mesh)
+    state = init_train_state(cfg, mesh, n_micro=args.microbatch)
+    params, opt_state = state.params, state.opt_state
     n_params = sum(int(np.prod(x.shape))
-                   for x in jax.tree_util.tree_leaves(params_shape))
+                   for x in jax.tree_util.tree_leaves(params))
     print(f"params: {n_params/1e6:.1f}M", flush=True)
-
-    with mesh:
-        init_fn = jax.jit(
-            lambda k: steps_lib.tf.init(k, cfg), out_shardings=pshard
-        )
-        params = init_fn(jax.random.PRNGKey(0))
-        opt_state = jax.jit(optimizer.init, out_shardings=None)(params)
 
     start_step = 0
     if args.ckpt_dir and ckpt.latest_step(args.ckpt_dir) is not None:
@@ -135,16 +161,14 @@ def main(argv=None) -> Dict[str, Any]:
         )
         # elastic re-lay onto the current mesh
         with mesh:
-            params = jax.jit(lambda x: x, out_shardings=pshard)(tree["params"])
-            opt_state = tree["opt"]
+            params, opt_state = jax.jit(
+                lambda x: x, out_shardings=(state.pshard, state.oshard)
+            )((tree["params"], tree["opt"]))
         print(f"restored step {start_step} from {args.ckpt_dir}", flush=True)
 
     data = synthetic.make_lm_dataset(synthetic.LMTokenSpec(
         vocab_size=cfg.vocab_size, seq_len=args.seq))
-    bspec = shard_lib.batch_specs(cfg, mesh, "train")
-    bshard = shard_lib.to_named(bspec, mesh)
-
-    step_fn = jax.jit(train_step, donate_argnums=(0, 1))
+    bshard, step_fn = state.bshard, state.step_fn
     history = []
     with mesh:
         for step in range(start_step, args.steps):

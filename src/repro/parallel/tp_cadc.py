@@ -29,7 +29,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import dendritic
 
@@ -82,7 +81,7 @@ def tp_cadc_linear(
 
     nd = x.ndim - 1
     xspec = P(*([None] * nd), axis)  # D split along segments
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(xspec, P(axis, None, None)),
         out_specs=P(*([None] * (nd + 1))),

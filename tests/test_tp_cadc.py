@@ -20,7 +20,8 @@ _BODY = textwrap.dedent("""
     from repro.parallel.tp_cadc import (segment_weights, tp_cadc_linear,
                                         tp_vconv_linear)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     B, D, N, XBAR = 8, 512, 128, 64          # S = 8 segments over 4 devices
     x = jax.random.normal(key, (B, D))
