@@ -230,10 +230,14 @@ def _stride_phases(x, pad_h, pad_w, stride, hq, wq):
     image with a unit phase axis."""
     s1, s2 = stride
     b, h, w, c = x.shape
-    # lax.pad: a negative high pad crops rows/cols no tap reads
-    xp = jax.lax.pad(x, jnp.zeros((), x.dtype),
-                     ((0, 0, 0), (pad_h[0], hq * s1 - h - pad_h[0], 0),
-                      (pad_w[0], wq * s2 - w - pad_w[0], 0), (0, 0, 0)))
+    # lax.pad: a negative high pad crops rows/cols no tap reads. Only the
+    # pad runs under the `phases` scope: XLA names instructions after the
+    # op names of the reshapes and transpose, so a scope around them would
+    # renumber instructions of the compiled program.
+    with jax.named_scope("phases"):
+        xp = jax.lax.pad(x, jnp.zeros((), x.dtype),
+                         ((0, 0, 0), (pad_h[0], hq * s1 - h - pad_h[0], 0),
+                          (pad_w[0], wq * s2 - w - pad_w[0], 0), (0, 0, 0)))
     xp = xp.reshape(b, hq, s1, wq, s2, c).transpose(0, 2, 4, 1, 3, 5)
     return xp.reshape(b, s1 * s2, hq, wq, c)
 
@@ -258,18 +262,24 @@ def _conv_pallas(x, w, *, f, gate_fn, gate_dt, gate_mode, crossbar_size,
     bn = min(block_n, cout)
     cout_pad = -(-cout // bn) * bn
     quantized = scale2 is not None
-    if quantized:
-        # int8 straight into the MXU; float primals of the STE path hold
-        # the same integer codes
-        x, w = x.astype(jnp.int8), w.astype(jnp.int8)
-    else:
-        dt = jnp.result_type(x.dtype, w.dtype)
-        x, w = x.astype(dt), w.astype(dt)
+    # The work around the kernel runs under named scopes (`phases`,
+    # `weights`, `crop`) that the benchmark's trace attribution reads; the
+    # Pallas call keeps the jitted wrapper's name, which the roofline
+    # readers match. Scopes are op-name metadata only.
+    with jax.named_scope("phases"):
+        if quantized:
+            # int8 straight into the MXU; float primals of the STE path
+            # hold the same integer codes
+            x, w = x.astype(jnp.int8), w.astype(jnp.int8)
+        else:
+            dt = jnp.result_type(x.dtype, w.dtype)
+            x, w = x.astype(dt), w.astype(dt)
     xph = _stride_phases(x, (pt, pb), (pl_, pr), stride,
                          oh_pad + (k1 - 1) // s1, ow + (k2 - 1) // s2)
-    w2d = w.reshape(k1 * k2 * cin, cout)
-    if cout_pad != cout:
-        w2d = jnp.pad(w2d, ((0, 0), (0, cout_pad - cout)))
+    with jax.named_scope("weights"):
+        w2d = w.reshape(k1 * k2 * cin, cout)
+        if cout_pad != cout:
+            w2d = jnp.pad(w2d, ((0, 0), (0, cout_pad - cout)))
 
     segs = _segment_taps(k1, k2, cin, crossbar_size)
     n_seg = len(segs)
@@ -324,14 +334,16 @@ def _conv_pallas(x, w, *, f, gate_fn, gate_dt, gate_mode, crossbar_size,
         ),
         interpret=interpret,
     )(*operands)
-    if with_gate:
-        y, gate = out
-        # Packed word columns cover the padded Cout and cannot be cropped
-        # bit-wise (padded channels carry zero bits — zero w columns).
-        gate = (gate[:, :, :oh] if gate_mode == "packed"
-                else gate[:, :, :oh, :, :cout])
-        return y[:, :oh, :, :cout], gate
-    return out[:, :oh, :, :cout], None
+    with jax.named_scope("crop"):
+        if with_gate:
+            y, gate = out
+            # Packed word columns cover the padded Cout and cannot be
+            # cropped bit-wise (padded channels carry zero bits — zero w
+            # columns).
+            gate = (gate[:, :, :oh] if gate_mode == "packed"
+                    else gate[:, :, :oh, :, :cout])
+            return y[:, :oh, :, :cout], gate
+        return out[:, :oh, :, :cout], None
 
 
 @functools.lru_cache(maxsize=None)

@@ -56,34 +56,47 @@ def init(key, *, num_classes: int = 10, in_ch: int = 3, width: int = 64):
 def _block_apply(p, s, x, ctx, *, stride, train, name):
     ns = {}
     h = cm.conv_forward(p["conv1"], x, ctx, stride=(stride, stride), name=f"{name}.conv1")
-    h, ns["bn1"] = cm.bn_forward(p["bn1"], s["bn1"], h, train=train)
-    h = jax.nn.relu(h)
+    h, ns["bn1"] = cm.bn_forward(p["bn1"], s["bn1"], h, train=train,
+                                 name="bn1")
+    with jax.named_scope("relu"):
+        h = jax.nn.relu(h)
     h = cm.conv_forward(p["conv2"], h, ctx, name=f"{name}.conv2")
-    h, ns["bn2"] = cm.bn_forward(p["bn2"], s["bn2"], h, train=train)
+    h, ns["bn2"] = cm.bn_forward(p["bn2"], s["bn2"], h, train=train,
+                                 name="bn2")
     if "proj" in p:
         sc = cm.conv_forward(p["proj"], x, ctx, stride=(stride, stride),
                              name=f"{name}.proj")
-        sc, ns["bnp"] = cm.bn_forward(p["bnp"], s["bnp"], sc, train=train)
+        sc, ns["bnp"] = cm.bn_forward(p["bnp"], s["bnp"], sc, train=train,
+                                      name="bnp")
     else:
         sc = x
-    return jax.nn.relu(h + sc), ns
+    with jax.named_scope("residual"):
+        return jax.nn.relu(h + sc), ns
 
 
 def apply(params, state, x, ctx: cm.Ctx, *, train: bool = False):
+    """Logits and the new BN state. Each part of the forward runs under a
+    named scope (`stem`, `s{stage}b{block}`, `head`; inside them the
+    layer, `bn*`, `relu` and `residual`), which the compiled program
+    carries as op-name metadata and nothing else."""
     new_state: Dict[str, Any] = {}
-    h = cm.conv_forward(params["stem"], x, ctx, name="stem")
-    h, new_state["bn_stem"] = cm.bn_forward(
-        params["bn_stem"], state["bn_stem"], h, train=train
-    )
-    h = jax.nn.relu(h)
+    with jax.named_scope("stem"):
+        h = cm.conv_forward(params["stem"], x, ctx, name="stem")
+        h, new_state["bn_stem"] = cm.bn_forward(
+            params["bn_stem"], state["bn_stem"], h, train=train
+        )
+        with jax.named_scope("relu"):
+            h = jax.nn.relu(h)
     for si, n_blocks in enumerate(STAGES):
         for bi in range(n_blocks):
             stride = 2 if (si > 0 and bi == 0) else 1
             name = f"s{si}b{bi}"
-            h, new_state[name] = _block_apply(
-                params[name], state[name], h, ctx,
-                stride=stride, train=train, name=name,
-            )
-    h = cm.global_avg_pool(h)
-    logits = cm.linear_forward(params["fc"], h, ctx, name="fc")
+            with jax.named_scope(name):
+                h, new_state[name] = _block_apply(
+                    params[name], state[name], h, ctx,
+                    stride=stride, train=train, name=name,
+                )
+    with jax.named_scope("head"):
+        h = cm.global_avg_pool(h)
+        logits = cm.linear_forward(params["fc"], h, ctx, name="fc")
     return logits, new_state

@@ -5,6 +5,11 @@ which dispatch on LayerMode.impl: 'vconv' (baseline partitioned matmul) or
 'cadc' (per-crossbar dendritic f()). Quantization (4/2/4b etc.) and the ADC
 noise model compose via the same mode. Psum sparsity statistics are collected
 through the Ctx object (pytree-compatible — works under jit).
+
+Each layer runs under a `jax.named_scope` of its short name (`conv1`, `fc`,
+`bn1`; on the int8 path also `quantize_act` and `quantize_weight`): op-name
+metadata of the compiled program, by which a profile's device time is
+attributed to layers, and nothing else.
 """
 from __future__ import annotations
 
@@ -143,7 +148,30 @@ def _use_q8(mode: LayerMode) -> bool:
             and mode.quant.weight_bits == 2 and mode.quant.input_bits <= 8)
 
 
+def _scope(name: str) -> str:
+    """The named scope of layer `name`: its last dotted part (`s1b0.conv1`
+    -> `conv1`), since the enclosing block has a scope of its own."""
+    return name.rpartition(".")[2]
+
+
+def _q8_operands(x: Array, w: Array, bits: int):
+    """(activation codes, weight codes, shared scale) of the int8 path,
+    each part under its own named scope. stop_gradient: inference-only
+    path — without it jax.grad would deliver a spurious
+    scale-direction-only "gradient" (int codes are float0)."""
+    with jax.named_scope("quantize_act"):
+        x_codes, lsb = quant_lib.quantize_codes(jax.lax.stop_gradient(x), bits)
+    with jax.named_scope("quantize_weight"):
+        w_codes, alpha = quant_lib.ternary_decompose(jax.lax.stop_gradient(w))
+    return x_codes, w_codes, jax.lax.stop_gradient(lsb * alpha)
+
+
 def linear_forward(p: Params, x: Array, ctx: Ctx, *, name: str = "fc") -> Array:
+    with jax.named_scope(_scope(name)):
+        return _linear(p, x, ctx, name)
+
+
+def _linear(p: Params, x: Array, ctx: Ctx, name: str) -> Array:
     from repro.kernels import ops as kops
 
     mode = ctx.mode
@@ -152,15 +180,11 @@ def linear_forward(p: Params, x: Array, ctx: Ctx, *, name: str = "fc") -> Array:
     if _use_q8(mode) and not want_ps and mode.adc is None:
         # Int8-native crossbar arithmetic (alpha * codes == ternarize(w)):
         # one shared fp32 scale, int32 psums, bit-exact vs the q8 oracle
-        # on every impl (the xla dispatch IS the oracle). stop_gradient:
-        # inference-only path — without it jax.grad would deliver a
-        # spurious scale-direction-only "gradient" (int codes are float0).
-        x_codes, lsb = quant_lib.quantize_codes(
-            jax.lax.stop_gradient(x), mode.quant.input_bits)
-        w_codes, alpha = quant_lib.ternary_decompose(
-            jax.lax.stop_gradient(p["w"]))
+        # on every impl (the xla dispatch IS the oracle).
+        x_codes, w_codes, scale = _q8_operands(x, p["w"],
+                                               mode.quant.input_bits)
         y = kops.cadc_matmul_q8(
-            x_codes, w_codes, jax.lax.stop_gradient(lsb * alpha),
+            x_codes, w_codes, scale,
             crossbar_size=mode.crossbar_size,
             fn=mode.dendritic_fn(), impl=mode.kernel,
             save_gate=mode.save_gate,
@@ -205,6 +229,11 @@ def conv_forward(
     padding="SAME",
     name: str = "conv",
 ) -> Array:
+    with jax.named_scope(_scope(name)):
+        return _conv(p, x, ctx, stride, padding, name)
+
+
+def _conv(p: Params, x: Array, ctx: Ctx, stride, padding, name: str) -> Array:
     from repro.kernels import ops as kops
 
     mode = ctx.mode
@@ -212,13 +241,11 @@ def conv_forward(
     segs = cadc_lib.num_segments(k1 * k2 * cin, mode.crossbar_size)
     want_ps = mode.collect_stats and segs > 1
     if _use_q8(mode) and not want_ps and mode.adc is None:
-        # Inference-only int8 path — stop_gradient as in linear_forward.
-        x_codes, lsb = quant_lib.quantize_codes(
-            jax.lax.stop_gradient(x), mode.quant.input_bits)
-        w_codes, alpha = quant_lib.ternary_decompose(
-            jax.lax.stop_gradient(p["w"]))
+        # Inference-only int8 path, as in _linear.
+        x_codes, w_codes, scale = _q8_operands(x, p["w"],
+                                               mode.quant.input_bits)
         return kops.cadc_conv2d_q8(
-            x_codes, w_codes, jax.lax.stop_gradient(lsb * alpha),
+            x_codes, w_codes, scale,
             crossbar_size=mode.crossbar_size,
             fn=mode.dendritic_fn(), stride=stride, padding=padding,
             impl=mode.kernel, save_gate=mode.save_gate,
@@ -260,8 +287,15 @@ def bn_init(c: int) -> Tuple[Params, Params]:
 
 
 def bn_forward(
-    p: Params, s: Params, x: Array, *, train: bool, momentum: float = 0.9
+    p: Params, s: Params, x: Array, *, train: bool, momentum: float = 0.9,
+    name: str = "bn",
 ) -> Tuple[Array, Params]:
+    with jax.named_scope(name):
+        return _bn(p, s, x, train, momentum)
+
+
+def _bn(p: Params, s: Params, x: Array, train: bool, momentum: float
+        ) -> Tuple[Array, Params]:
     axes = tuple(range(x.ndim - 1))
     if train:
         mean = jnp.mean(x, axes)

@@ -1,4 +1,6 @@
 """Smoke + behaviour tests for the paper's four CNN benchmarks."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -128,3 +130,44 @@ class TestQuantizedAndNoisy:
         for name in ["c1", "c2"]:  # surrogate grads reach the convs
             gn = float(jnp.abs(g[name]["w"]).sum())
             assert np.isfinite(gn) and gn > 0, name
+
+
+class TestNamedScopes:
+    """ResNet-18's parts run under named scopes, which reach the compiled
+    program as op-name metadata (a benchmark attributes device time by
+    them), and the psum statistics keep their labels."""
+
+    SCOPES = ["stem/stem/", "stem/bn/", "stem/relu/", "s1b0/conv1/",
+              "s1b0/bn1/", "s1b0/relu/", "s1b0/conv2/", "s1b0/bn2/",
+              "s1b0/proj/", "s1b0/bnp/", "s1b0/residual/", "s3b1/conv2/",
+              "head/fc/"]
+
+    @pytest.mark.parametrize("q8", [False, True])
+    def test_scopes_in_the_compiled_program(self, q8):
+        params, state = resnet18.init(KEY, num_classes=10, width=8)
+        mode = LayerMode(impl="cadc", crossbar_size=64, kernel="xla",
+                         quant=PAPER_424 if q8 else LayerMode().quant,
+                         q8_fused=q8)
+        x = jax.random.normal(KEY, (2, 32, 32, 3))
+        text = jax.jit(
+            lambda p, s, x: resnet18.apply(p, s, x, Ctx(mode))[0]
+        ).lower(params, state, x).compile().as_text()
+        names = " ".join(re.findall(r'op_name="([^"]*)"', text))
+        want = self.SCOPES + (["s1b0/conv1/quantize_act/",
+                               "s1b0/conv1/quantize_weight/",
+                               "head/fc/quantize_act/"] if q8 else [])
+        for scope in want:
+            assert "/" + scope in names, scope
+
+    def test_stats_labels_unchanged(self):
+        params, state = resnet18.init(KEY, num_classes=10, width=8)
+        ctx = Ctx(LayerMode(impl="cadc", crossbar_size=64,
+                            collect_stats=True))
+        x = jax.random.normal(KEY, (2, 32, 32, 3))
+        resnet18.apply(params, state, x, ctx)
+        # the layers of more than one 64-row crossbar: at width 8 every
+        # 3x3 conv of a block (72 rows or more), no 1x1 projection (at most
+        # 32), not the stem (27) nor the classifier (64)
+        want = [f"s{si}b{bi}.{layer}" for si in range(4) for bi in range(2)
+                for layer in ("conv1", "conv2")]
+        assert sorted(ctx.stats_dict()) == sorted(want)

@@ -9,13 +9,17 @@ one process at a time may load the TPU compiler library, and every test
 worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.quant import FP32, PAPER_424
 from repro.kernels import ops
+from repro.models.cnn import resnet18
+from repro.models.common import Ctx, LayerMode
 
 # gemma3-1b CADC linears (contraction padded to the 256 crossbar) at decode
 # (M = 8 slots) and prefill (M = 4096 tokens) rows
@@ -113,3 +117,112 @@ def test_resnet18_classifier(one_chip, q8):
         _compile_has_kernel(
             lambda x, w: ops.cadc_matmul(x, w, crossbar_size=64,
                                          impl="pallas"), x, w)
+
+
+# ResNet-18 forward (width 64, crossbar 64, Pallas kernels) at batch 8: the
+# custom calls keep the names that the benchmark's roofline readers match,
+# and the forward's named scopes reach every instruction the chip runs
+RESNET_KERNELS = {False: ("_conv_jit", "cadc_matmul_pallas"),
+                  True: ("_conv_q8_jit", "cadc_matmul_q8_pallas")}
+# name-stack words JAX writes itself; components with "(" are transforms
+JAX_WORDS = {"while", "body", "cond", "closed_call"}
+# instructions with no device work of their own
+NO_WORK = {"parameter", "constant", "bitcast", "tuple", "get-tuple-element"}
+CONV_LAYER = re.compile(r"stem/stem|s[0-3]b[01]/(conv1|conv2|proj)")
+
+
+@pytest.fixture(scope="module")
+def resnet18_hlo(one_chip):
+    """{q8: compiled text of the forward} for fp32 and the 4/2/4b path."""
+    p, s = jax.eval_shape(lambda k: resnet18.init(k, width=64),
+                          jax.random.PRNGKey(0))
+    p, s = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype, one_chip), (p, s))
+    x = _spec((8, 32, 32, 3), jnp.float32, one_chip)
+    out = {}
+    for q8 in (False, True):
+        mode = LayerMode(impl="cadc", crossbar_size=64, kernel="pallas",
+                         quant=PAPER_424 if q8 else FP32, q8_fused=q8)
+        out[q8] = jax.jit(
+            lambda p, s, x: resnet18.apply(p, s, x, Ctx(mode))[0]
+        ).lower(p, s, x).compile().as_text()
+    return out
+
+
+def _entry_ops(text):
+    """(name, opcode, op name, operands) of each instruction of the entry
+    computation. A fusion's op name is its fused computation's root's
+    where the root has one; an op name that is only an argument's name
+    (the layout copy of an argument) counts as none."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(ENTRY )?%(\S+) .*\{$", line)
+        if m:
+            cur = "ENTRY" if m.group(1) else m.group(2)
+            comps[cur] = []
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            m = re.match(r"^\s+(ROOT )?%(\S+) = .*?\s([a-z][a-z0-9-]*)"
+                         r"\(([^)]*)\)", line)
+            if m:
+                name = re.search(r'op_name="([^"]*/[^"]*)"', line)
+                calls = re.search(r"calls=%([\w.\-]+)", line)
+                comps[cur].append((m.group(2), m.group(3),
+                                   name.group(1) if name else None,
+                                   re.findall(r"%([\w.\-]+)", m.group(4)),
+                                   calls.group(1) if calls else None,
+                                   bool(m.group(1))))
+    out = []
+    for name, op, op_name, args, calls, _ in comps["ENTRY"]:
+        if op == "fusion":
+            op_name = next(n for _, _, n, _, _, root in comps[calls]
+                           if root) or op_name
+        out.append((name, op, op_name, args))
+    return out
+
+
+def _scope(op_name):
+    parts = op_name.split(";")[0].split("/")[:-1]
+    return "/".join(p for p in parts if p not in JAX_WORDS and "(" not in p)
+
+
+@pytest.mark.parametrize("q8", [False, True])
+def test_resnet18_kernel_names(resnet18_hlo, q8):
+    conv, fc = RESNET_KERNELS[q8]
+    calls = [n for n, op, _, _ in _entry_ops(resnet18_hlo[q8])
+             if op == "custom-call"]
+    assert len([n for n in calls if re.fullmatch(rf"{conv}\.\d+", n)]) == 20
+    assert len([n for n in calls if re.fullmatch(rf"{fc}\.\d+", n)]) == 1
+
+
+@pytest.mark.parametrize("q8", [False, True])
+def test_resnet18_conv_scopes(resnet18_hlo, q8):
+    conv = RESNET_KERNELS[q8][0]
+    scopes = [_scope(op_name) for n, _, op_name, _ in
+              _entry_ops(resnet18_hlo[q8]) if n.startswith(conv + ".")]
+    assert len(set(scopes)) == 20
+    assert all(CONV_LAYER.fullmatch(s) for s in scopes), scopes
+
+
+@pytest.mark.parametrize("q8", [False, True])
+def test_resnet18_every_op_has_a_scope(resnet18_hlo, q8):
+    """Each instruction the chip runs carries a scope of the forward, or,
+    made by the compiler without an op name (a layout copy, an async copy
+    or slice of a weight, the concatenation of its slices), feeds one
+    that does: the rule by which the benchmark charges it."""
+    ops = _entry_ops(resnet18_hlo[q8])
+    users = {}
+    for n, _, _, args in ops:
+        for a in args:
+            users.setdefault(a, []).append(n)
+    scope = {n: _scope(op_name) if op_name else None
+             for n, _, op_name, _ in ops}
+
+    def charged(n):
+        if scope[n] is None:
+            return any(charged(u) for u in users.get(n, []))
+        return bool(scope[n])
+
+    bare = [n for n, op, _, _ in ops if op not in NO_WORK and not charged(n)]
+    assert not bare
