@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import cadc as _core
+from repro.kernels import cadc_conv as _ck
 from repro.kernels import cadc_matmul as _pk
 
 Array = jnp.ndarray
@@ -181,7 +182,7 @@ def cadc_conv2d(
     impl: str = "auto",
     block_h: int = 8,
     block_n: int = 128,
-    vmem_budget_bytes: int = 8 * 2**20,
+    vmem_budget_bytes: int = _ck.FMAP_VMEM_BUDGET,
     save_gate: str = "auto",
 ) -> Array:
     """Fused im2col + segmented conv (psums and patches never hit HBM).
@@ -201,8 +202,6 @@ def cadc_conv2d(
             x, w, crossbar_size=crossbar_size, fn=fn, stride=stride,
             padding=padding,
         )
-    from repro.kernels import cadc_conv as _ck
-
     return _ck.cadc_conv2d_pallas(
         x, w, crossbar_size=crossbar_size, fn=fn, stride=tuple(stride),
         padding=padding, block_h=block_h, block_n=block_n,
@@ -222,7 +221,7 @@ def cadc_conv2d_q8(
     impl: str = "auto",
     block_h: int = 8,
     block_n: int = 128,
-    vmem_budget_bytes: int = 8 * 2**20,
+    vmem_budget_bytes: int = _ck.FMAP_VMEM_BUDGET,
     save_gate: str = "auto",
 ) -> Array:
     """Quantized fused conv (int8 taps -> int32 psums -> dequant -> f()).
@@ -243,8 +242,6 @@ def cadc_conv2d_q8(
             x_q, w_codes, scale, crossbar_size=crossbar_size, fn=fn,
             stride=stride, padding=padding,
         )
-    from repro.kernels import cadc_conv as _ck
-
     return _ck.cadc_conv2d_q8_pallas(
         x_q, w_codes, scale, crossbar_size=crossbar_size, fn=fn,
         stride=tuple(stride), padding=padding, block_h=block_h,

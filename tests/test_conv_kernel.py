@@ -8,7 +8,9 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core.conv import cadc_conv2d, vconv_conv2d
 from repro.kernels import ops
-from repro.kernels.cadc_conv import cadc_conv2d_pallas, _segment_taps
+from repro.kernels.cadc_conv import (FMAP_VMEM_BUDGET, ROW_TARGET,
+                                     ConvPlan, cadc_conv2d_pallas,
+                                     conv_block_plan, _segment_taps)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -29,6 +31,22 @@ SWEEP = [
     (1, 10, 10, 6, 8, 1, 1, 4, "relu"),          # 1x1 conv
     (2, 9, 9, 20, 12, 3, 1, 64, "supralinear"),  # segment spans taps
 ]
+# Small maps, where a grid step holds several images: b, h, cin, cout,
+# stride, the images a step (nb) and whether the Cout blocks are the
+# outermost grid axis. Cin = 2 crossbars of 64; B = 6 at 8x8 takes its
+# divisor 3; the prime B = 5 at 8x8 keeps one image a step; B = 8 over
+# two Cout blocks fetches fewer bytes with the Cout blocks outermost.
+MULTI_IMAGE = [
+    (4, 4, 128, 64, 1, 4, False),
+    (4, 2, 128, 32, 1, 4, False),
+    (6, 8, 128, 32, 1, 3, False),
+    (6, 8, 128, 32, 2, 6, False),
+    (4, 4, 128, 256, 2, 4, False),
+    (5, 8, 128, 32, 1, 1, False),
+    (8, 8, 8, 256, 1, 4, True),
+]
+SWEEP += [(b, h, h, cin, cout, 3, s, 64, "relu")
+          for b, h, cin, cout, s, _, _ in MULTI_IMAGE]
 
 
 @pytest.mark.parametrize("b,h,w,cin,cout,k,s,xbar,fn", SWEEP)
@@ -40,6 +58,69 @@ def test_fused_conv_matches_oracle(b, h, w, cin, cout, k, s, xbar, fn):
                              padding="SAME", interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("b,h,cin,cout,s,nb,cols", MULTI_IMAGE)
+def test_multi_image_plan(b, h, cin, cout, s, nb, cols):
+    plan = conv_block_plan((b, h, h, cin), (3, 3, cin, cout), stride=(s, s))
+    assert (plan.nb, plan.cols_outer) == (nb, cols)
+
+
+@pytest.mark.parametrize("save_gate", ["packed", "bytes"])
+@pytest.mark.parametrize("b,h,cin,cout,s,nb,cols", MULTI_IMAGE)
+def test_multi_image_grads(b, h, cin, cout, s, nb, cols, save_gate):
+    """The gate block of a step covers its nb images: gradients through
+    the saved gate match XLA autodiff of the oracle."""
+    x, wt = _mk(b, h, h, cin, cout, 3)
+    r = jax.random.normal(jax.random.fold_in(KEY, 99),
+                          cadc_conv2d(x, wt, crossbar_size=64,
+                                      stride=(s, s)).shape)
+
+    def grads(conv):
+        return jax.grad(lambda a, w: jnp.vdot(conv(a, w), r),
+                        argnums=(0, 1))(x, wt)
+
+    got = grads(lambda a, w: cadc_conv2d_pallas(
+        a, w, crossbar_size=64, fn="relu", stride=(s, s), interpret=True,
+        save_gate=save_gate))
+    want = grads(lambda a, w: cadc_conv2d(a, w, crossbar_size=64, fn="relu",
+                                          stride=(s, s)))
+    for g, h_ in zip(got, want):
+        assert float(jnp.max(jnp.abs(g - h_))) <= 1e-4
+
+
+# ResNet-18 (CIFAR, width 64, crossbar 64) at batch 256: (H, Cin, Cout, k,
+# stride) of each distinct conv, the calls of one forward that run it, and
+# its block plan (nb, bh, bn, rows a dot, grid steps, Cout blocks outermost)
+RESNET18_B256 = [
+    ((32, 3, 64, 3, 1), 1, (1, 8, 64, 256, 1024, False)),      # stem
+    ((32, 64, 64, 3, 1), 4, (1, 8, 64, 256, 1024, False)),     # s0
+    ((32, 64, 128, 3, 2), 1, (2, 8, 128, 256, 256, False)),    # s1b0 conv1
+    ((32, 64, 128, 1, 2), 1, (2, 8, 128, 256, 256, False)),    # s1b0 proj
+    ((16, 128, 128, 3, 1), 3, (2, 8, 128, 256, 256, False)),   # s1
+    ((16, 128, 256, 3, 2), 1, (4, 8, 128, 256, 128, True)),    # s2b0 conv1
+    ((16, 128, 256, 1, 2), 1, (4, 8, 128, 256, 128, False)),   # s2b0 proj
+    ((8, 256, 256, 3, 1), 3, (4, 8, 128, 256, 128, True)),     # s2
+    ((8, 256, 512, 3, 2), 1, (16, 4, 128, 256, 64, False)),    # s3b0 conv1
+    ((8, 256, 512, 1, 2), 1, (16, 4, 128, 256, 64, False)),    # s3b0 proj
+    ((4, 512, 512, 3, 1), 3, (16, 4, 128, 256, 64, True)),     # s3
+]
+
+
+@pytest.mark.parametrize("itemsize", [4, 1])
+def test_resnet18_block_plan(itemsize):
+    """The plan of the 20 convs of a batch-256 forward: every dot streams
+    ROW_TARGET rows, the stem and stage 0 keep one image a step and the
+    batch outermost (their kernel is the one-image kernel), the step's
+    images fit the budget."""
+    assert sum(n for _, n, _ in RESNET18_B256) == 20
+    for (h, cin, cout, k, s), _, want in RESNET18_B256:
+        plan = conv_block_plan((256, h, h, cin), (k, k, cin, cout),
+                               stride=(s, s), itemsize=itemsize)
+        assert plan == ConvPlan(*want), (h, cin, cout, k, s)
+        assert plan.rows >= ROW_TARGET
+        hq = -(-h // s) + (k - 1) // s
+        assert plan.nb * s * s * hq * hq * cin * itemsize <= FMAP_VMEM_BUDGET
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -15,6 +15,22 @@ from repro.kernels.cadc_conv import cadc_conv2d_q8_pallas
 KEY = jax.random.PRNGKey(0)
 TOL = 1e-4
 XBARS = [64, 128, 256]
+# Small maps, where a grid step holds several images (b, h, cin, cout,
+# stride; the plan of each in tests/test_conv_kernel.py): 4x4 and 2x2 maps
+# with Cin = 2 crossbars of 64, B = 6 (nb = 3 at 8x8) and the prime B = 5
+# (nb = 1).
+MULTI_IMAGE = [
+    (4, 4, 128, 64, 1),
+    (4, 2, 128, 32, 1),
+    (6, 8, 128, 32, 1),
+    (6, 8, 128, 32, 2),
+    (4, 4, 128, 256, 2),
+    (5, 8, 128, 32, 1),
+]
+# B = 8 over two Cout blocks, the outermost grid axis. Its gradients are
+# checked in fp32 (tests/test_conv_kernel.py): on these integer codes they
+# reach ~200, where the backward's fp32 rounding exceeds the absolute TOL.
+COLS_OUTER = (8, 8, 8, 256, 1)
 
 
 def _mk_q8(b, h, w, cin, cout, k, seed=0):
@@ -45,6 +61,15 @@ class TestQ8ConvBitExact:
         want = ref.cadc_conv2d_q8_ref(x_q, w_c, sc, crossbar_size=64,
                                       fn="relu", stride=stride,
                                       padding=padding)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("b,h,cin,cout,s", MULTI_IMAGE + [COLS_OUTER])
+    def test_multi_image_bitexact(self, b, h, cin, cout, s):
+        x_q, w_c, sc = _mk_q8(b, h, h, cin, cout, 3, seed=b * h + s)
+        got = cadc_conv2d_q8_pallas(x_q, w_c, sc, crossbar_size=64,
+                                    fn="relu", stride=(s, s), interpret=True)
+        want = ref.cadc_conv2d_q8_ref(x_q, w_c, sc, crossbar_size=64,
+                                      fn="relu", stride=(s, s))
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_ops_dispatch_xla_is_oracle(self):
@@ -81,20 +106,17 @@ class TestQ8ConvGrads:
                      @ w2[i * xbar:(i + 1) * xbar]))
         return acc
 
-    @pytest.mark.parametrize("xbar", XBARS)
-    @pytest.mark.parametrize("save_gate", ["packed", "recompute"])
-    def test_parity(self, xbar, save_gate):
-        # cout=32 keeps bn % 32 == 0 so "packed" is genuinely packed.
-        x_q, w_c, sc = _mk_q8(1, 8, 8, 20, 32, 3, seed=xbar + 1)
+    def _check_parity(self, x_q, w_c, sc, *, xbar, save_gate,
+                      stride=(1, 1), block_n=128):
         xf, wf = x_q.astype(jnp.float32), w_c.astype(jnp.float32)
 
         def pallas_op(a, b, s):
             return cadc_conv2d_q8_pallas(
-                a, b, s, crossbar_size=xbar, fn="relu", block_n=32,
-                interpret=True, save_gate=save_gate)
+                a, b, s, crossbar_size=xbar, fn="relu", stride=stride,
+                block_n=block_n, interpret=True, save_gate=save_gate)
 
         def oracle(a, b, s):
-            return self._float_oracle(a, b, s, xbar=xbar)
+            return self._float_oracle(a, b, s, xbar=xbar, stride=stride)
 
         y = pallas_op(xf, wf, sc)
         r = jax.random.normal(jax.random.fold_in(KEY, 99), y.shape)
@@ -107,6 +129,21 @@ class TestQ8ConvGrads:
         assert float(jnp.max(jnp.abs(gx - hx))) <= TOL
         assert float(jnp.max(jnp.abs(gw - hw))) <= TOL
         assert abs(float(gs - hs)) <= TOL * max(1.0, abs(float(hs)))
+
+    @pytest.mark.parametrize("xbar", XBARS)
+    @pytest.mark.parametrize("save_gate", ["packed", "recompute"])
+    def test_parity(self, xbar, save_gate):
+        # cout=32 keeps bn % 32 == 0 so "packed" is genuinely packed.
+        x_q, w_c, sc = _mk_q8(1, 8, 8, 20, 32, 3, seed=xbar + 1)
+        self._check_parity(x_q, w_c, sc, xbar=xbar, save_gate=save_gate,
+                           block_n=32)
+
+    @pytest.mark.parametrize("save_gate", ["packed", "bytes"])
+    @pytest.mark.parametrize("b,h,cin,cout,s", MULTI_IMAGE)
+    def test_multi_image_parity(self, b, h, cin, cout, s, save_gate):
+        x_q, w_c, sc = _mk_q8(b, h, h, cin, cout, 3, seed=b * h + s + 1)
+        self._check_parity(x_q, w_c, sc, xbar=64, save_gate=save_gate,
+                           stride=(s, s))
 
     def test_int_primals_get_float0_scale_grad_flows(self):
         x_q, w_c, sc = _mk_q8(1, 6, 6, 16, 8, 3, seed=31)

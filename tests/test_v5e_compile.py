@@ -1,8 +1,9 @@
 """The fused kernels compile for a TPU v5e chip at the shapes chip_smoke.py
 runs: gemma3-1b serving widths and ResNet-18 (CIFAR-10, width 64, batch
-128, crossbar 64). The chip is described, not attached (TPU compiler only,
-nothing runs), so these catch what interpret mode cannot: block shapes the
-chip's tiling refuses, unsupported loads, VMEM overruns.
+128, crossbar 64), and ResNet-18's convs at the benchmark's batch of 256.
+The chip is described, not attached (TPU compiler only, nothing runs), so
+these catch what interpret mode cannot: block shapes the chip's tiling
+refuses, unsupported loads, VMEM overruns.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU compiler library, and every test
@@ -83,23 +84,38 @@ def test_paged_attention_gemma3(one_chip, kind, ring):
         _spec((slots,), jnp.int32, one_chip))
 
 
+def _compile_conv(sharding, batch, q8, h, cin, cout, k, s):
+    dt = jnp.int8 if q8 else jnp.float32
+    x = _spec((batch, h, h, cin), dt, sharding)
+    w = _spec((k, k, cin, cout), dt, sharding)
+    if q8:
+        _compile_has_kernel(
+            lambda x, w, sc: ops.cadc_conv2d_q8(
+                x, w, sc, crossbar_size=64, stride=(s, s), impl="pallas"),
+            x, w, _spec((), jnp.float32, sharding))
+    else:
+        _compile_has_kernel(
+            lambda x, w: ops.cadc_conv2d(x, w, crossbar_size=64,
+                                         stride=(s, s), impl="pallas"),
+            x, w)
+
+
 @pytest.mark.parametrize("h,cin,cout,k,s", CONVS)
 def test_cadc_conv2d_fp32(one_chip, h, cin, cout, k, s):
-    _compile_has_kernel(
-        lambda x, w: ops.cadc_conv2d(x, w, crossbar_size=64, stride=(s, s),
-                                     impl="pallas"),
-        _spec((BATCH, h, h, cin), jnp.float32, one_chip),
-        _spec((k, k, cin, cout), jnp.float32, one_chip))
+    _compile_conv(one_chip, BATCH, False, h, cin, cout, k, s)
 
 
 @pytest.mark.parametrize("h,cin,cout,k,s", CONVS)
 def test_cadc_conv2d_q8(one_chip, h, cin, cout, k, s):
-    _compile_has_kernel(
-        lambda x, w, sc: ops.cadc_conv2d_q8(x, w, sc, crossbar_size=64,
-                                            stride=(s, s), impl="pallas"),
-        _spec((BATCH, h, h, cin), jnp.int8, one_chip),
-        _spec((k, k, cin, cout), jnp.int8, one_chip),
-        _spec((), jnp.float32, one_chip))
+    _compile_conv(one_chip, BATCH, True, h, cin, cout, k, s)
+
+
+# Stages 1-3 at the benchmark's batch of 256, where a grid step holds
+# several images (kernels/cadc_conv.conv_block_plan)
+@pytest.mark.parametrize("q8", [False, True])
+@pytest.mark.parametrize("h,cin,cout,k,s", CONVS[2:])
+def test_cadc_conv2d_b256(one_chip, h, cin, cout, k, s, q8):
+    _compile_conv(one_chip, 256, q8, h, cin, cout, k, s)
 
 
 @pytest.mark.parametrize("q8", [False, True])
