@@ -7,8 +7,10 @@ each run exactly as `run.py` would but with a short window, and reads two
 sets of numbers on the same checked sample: the program's (every number
 its driver reads, `readings`, or else its `checks`) and the control's,
 which is the plain reference computed one precision below the one the
-configuration states, put in the program's place. A limit sits
-above the largest program reading and below the smallest control reading.
+configuration states, put in the program's place. The control's
+readings go through the same check as the program's, against the same
+limits: `control_correct` has to come out false. A limit sits above the
+largest program reading and below the smallest control reading.
 Prints one JSON line per seed and a summary line last. Needs the chip, as
 `run.py` does; the benchmark's own runs never read the control.
 """
@@ -20,6 +22,21 @@ import sys
 import time
 
 import run
+import session
+
+
+def control_checks(out: dict) -> dict:
+    """The control's readings that the program's checks compare, each
+    beside the program's own limit."""
+    return {k: session.check(v, out["checks"][k]["limit"])
+            for k, v in out["record"]["control"].items() if k in out["checks"]}
+
+
+def control_correct(out: dict) -> bool:
+    """`correct` as the harness decides it, of the control put in the
+    program's place."""
+    return all(c["value"] <= c["limit"]
+               for c in control_checks(out).values())
 
 
 def main(argv=None) -> int:
@@ -39,7 +56,8 @@ def main(argv=None) -> int:
                 "compiles_in_window": out["compiles_in_window"],
                 "program": rec.get("readings") or {
                     k: c["value"] for k, c in out["checks"].items()},
-                "control": rec["control"]}
+                "control": rec["control"],
+                "control_correct": control_correct(out)}
         print(json.dumps(line), flush=True)
         for k, v in line["program"].items():
             program.setdefault(k, []).append(v)

@@ -5,8 +5,6 @@ from __future__ import annotations
 import math
 from typing import Dict, List
 
-import numpy as np
-
 
 def percentile(values: List[float], q: float) -> float:
     """Nearest-rank percentile: the smallest value with at least q% of
@@ -25,16 +23,6 @@ def ttfts_s(rec: Dict) -> List[float]:
     it was due; inf for one that got none."""
     return [(r["times"][0] - r["due"]) if r["times"] else math.inf
             for r in rec["requests"] if r["due"] < rec["t_end"]]
-
-
-def token_gaps_s(rec: Dict) -> List[float]:
-    """Every gap between consecutive output tokens of a request that ends
-    inside the window."""
-    out = []
-    for r in rec["requests"]:
-        t = [x for x in r["times"] if x <= rec["t_end"]]
-        out.extend(np.diff(t).tolist())
-    return out
 
 
 def window_steps(rec: Dict) -> List[Dict]:

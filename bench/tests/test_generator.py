@@ -24,11 +24,10 @@ def test_every_seed_gets_the_same_work(name):
     mix = generator.load(name)
     a = generator.schedule(mix, 7, 20.0, 1000)
     b = generator.schedule(mix, BIG, 20.0, 1000)
-    assert sorted(a.max_new) == sorted(b.max_new)
-    assert sorted(p.size for p in a.prompts) == \
-        sorted(p.size for p in b.prompts)
-    gaps = lambda t: np.sort(np.diff(np.concatenate([[0.0], t])))
-    np.testing.assert_allclose(gaps(a.arrival_s), gaps(b.arrival_s))
+    np.testing.assert_array_equal(a.max_new, b.max_new)
+    np.testing.assert_array_equal([p.size for p in a.prompts],
+                                  [p.size for p in b.prompts])
+    np.testing.assert_array_equal(a.arrival_s, b.arrival_s)
     assert any(not np.array_equal(x, y) for x, y in zip(a.prompts, b.prompts))
 
 
@@ -46,11 +45,17 @@ def test_lengths_within_the_mix(name):
 
 
 def test_poisson_rate_and_enough_arrivals():
+    """Every seed's window holds the rate's count of arrivals, all inside
+    it, at the same times."""
     mix = generator.load("chat")
-    s = generator.schedule(mix, 3, 60.0, 1000)
-    assert s.arrival_s[-1] > 60.0
-    rate = (len(s) - 1) / s.arrival_s[-1]
-    assert rate == pytest.approx(mix["rate_per_s"], rel=0.2)
+    a = generator.schedule(mix, 3, 60.0, 1000)
+    b = generator.schedule(mix, BIG, 60.0, 1000)
+    for s in (a, b):
+        assert len(s) == round(mix["rate_per_s"] * 60.0)
+        assert 0.0 <= s.arrival_s[0] and s.arrival_s[-1] < 60.0
+    np.testing.assert_array_equal(a.arrival_s, b.arrival_s)
+    gaps = np.diff(a.arrival_s)
+    assert 1 / gaps.mean() == pytest.approx(mix["rate_per_s"], rel=0.2)
 
 
 def test_warm_up_covers_every_prefill_bucket():

@@ -9,16 +9,19 @@ Two kinds of mix:
   ``rate_per_s``, or every request due at t = 0), prompt and output
   lengths from clipped lognormals, prompt token ids.
 
-Every seed gets the same multiset of lengths and of inter-arrival gaps,
-drawn once from the mix's own ``base_seed``; the run's seed only orders
-them and draws the token ids. Runs on different seeds then do the same
-work, and their spread is the system's, not the sample's.
+Every seed gets the same schedule: the lengths and the inter-arrival
+gaps, in their order, are drawn once from the mix's own ``base_seed``;
+the run's seed draws only the token ids. A Poisson window of T seconds
+holds exactly round(rate * T) arrivals, as a Poisson process given its
+count does. Runs on different seeds then do the same work in the same
+order, and their spread is the system's, not the sample's: at a load
+near the knee the order of arrivals alone moves the tail of time to
+first token several-fold.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
-import math
 from pathlib import Path
 from typing import Dict, List
 
@@ -62,10 +65,10 @@ class Schedule:
 
 def n_requests(mix: Dict, seconds: float) -> int:
     """How many requests a window of `seconds` is given: the mix's fixed
-    backlog, or enough Poisson arrivals to outlast the window."""
+    backlog, or the Poisson arrivals that the rate puts in the window."""
     if mix["arrival"] == "backlog":
         return int(mix["requests"])
-    return int(math.ceil(mix["rate_per_s"] * seconds * 1.25)) + 16
+    return max(1, int(round(mix["rate_per_s"] * seconds)))
 
 
 def schedule(mix: Dict, seed: int, seconds: float, vocab_size: int
@@ -74,14 +77,13 @@ def schedule(mix: Dict, seed: int, seconds: float, vocab_size: int
     base = rng(mix["base_seed"], n)
     prompt_len = _lengths(mix["prompt"], n, base)
     max_new = _lengths(mix["output"], n, base)
-    order = rng(seed, 1).permutation(n)
-    prompt_len, max_new = prompt_len[order], max_new[order]
     if mix["arrival"] == "backlog":
         arrival = np.zeros(n)
     elif mix["arrival"] == "poisson":
-        gaps = base.exponential(1.0 / mix["rate_per_s"], n)
-        gaps = gaps[rng(seed, 2).permutation(n)]
-        arrival = np.cumsum(gaps)
+        # n + 1 exponential gaps scaled to sum to the window: their first
+        # n partial sums are n uniform order statistics in [0, seconds)
+        gaps = base.exponential(1.0 / mix["rate_per_s"], n + 1)
+        arrival = np.cumsum(gaps)[:n] * (seconds / gaps.sum())
     else:
         raise ValueError(f"unknown arrival process {mix['arrival']!r}")
     tok = rng(seed, 3)
