@@ -35,7 +35,24 @@ class MoEConfig:
     d_expert: int = 0          # per-expert FFN hidden dim
     n_shared: int = 0          # shared (always-on) experts
     d_shared: int = 0          # shared-expert hidden dim
+    # 0 = no drops: every routed (token, expert) pair is computed, rows
+    # sorted by expert through the grouped CADC matmul (kernels/cadc_gmm)
     capacity_factor: float = 1.25
+    # routing: 'softmax' over all experts, or 'sigmoid' scores with an
+    # optional selection-only bias (the top-k is taken of score + bias,
+    # the weights are the k scores renormalised)
+    score: str = "softmax"
+    expert_bias: bool = False
+    # the experts this chip holds: ids [first_expert, first_expert +
+    # n_held) of n_experts (0 = all). The router spans all n_experts; the
+    # layer adds only what its own experts give (expert parallelism's
+    # share, without the exchange)
+    first_expert: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,12 +68,19 @@ class ArchConfig:
     vocab_size: int
 
     # block layout: cycled over layers. entries: 'global' | 'local' |
-    # 'rglru' | 'mlstm' | 'slstm'
+    # 'rglru' | 'mlstm' | 'slstm' | 'conv' (gated short convolution)
     pattern: Tuple[str, ...] = ("global",)
+    # per-layer mixer kinds of the whole published stack, where it is not
+    # a cycle of `pattern` (the first n_layers are used); `pattern` is
+    # then the unit the layer scan repeats
+    layer_kinds: Tuple[str, ...] = ()
+    # leading layers with a dense FFN (d_ff) before the MoE layers
+    n_dense_layers: int = 0
     local_window: int = 4096
     ffn_type: str = "swiglu"     # swiglu | geglu | gelu | none
     attn_qkv_bias: bool = False
     attn_logit_softcap: Optional[float] = None
+    qk_norm: bool = False        # RMSNorm over head_dim on q and k
     rope_theta: float = 10_000.0
     is_encoder: bool = False
     tie_embeddings: bool = True
@@ -157,6 +181,8 @@ class ArchConfig:
 
     @property
     def pattern_for_layers(self) -> Tuple[str, ...]:
+        if self.layer_kinds:
+            return tuple(self.layer_kinds[: self.n_layers])
         reps = -(-self.n_layers // len(self.pattern))
         return (self.pattern * reps)[: self.n_layers]
 
@@ -214,14 +240,18 @@ ARCH_IDS = [
     "hubert_xlarge",
 ]
 
+# configurations served by the chip benchmark beyond the assigned table
+SERVE_ARCH_IDS = ["lfm2_8b_a1b"]
+
 # paper-side CNN configs are registered too (for --arch symmetry)
 CNN_IDS = ["lenet5", "resnet18", "vgg16", "snn_dvs"]
 
 
 def get_config(arch_id: str, **overrides) -> ArchConfig:
     arch_id = arch_id.replace("-", "_").replace(".", "_")
-    if arch_id not in ARCH_IDS:
-        raise ValueError(f"unknown arch {arch_id!r}; available: {ARCH_IDS}")
+    if arch_id not in ARCH_IDS + SERVE_ARCH_IDS:
+        raise ValueError(f"unknown arch {arch_id!r}; available: "
+                         f"{ARCH_IDS + SERVE_ARCH_IDS}")
     mod = importlib.import_module(f"repro.configs.{arch_id}")
     cfg: ArchConfig = mod.CONFIG
     if overrides:

@@ -174,12 +174,12 @@ def _acc_first(acc_ref, fps, chunked: bool):
 def _flush(o_ref, acc_ref, chunked: bool):
     """One output write per tile — after the last D-chunk when chunked."""
     if not chunked:
-        o_ref[...] = acc_ref[...]
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
         return
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _write():
-        o_ref[...] = acc_ref[...]
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 def _kernel(x_ref, w_ref, o_ref, acc_ref, *, fn: Callable, n_seg: int,

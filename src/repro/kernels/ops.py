@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from repro.core import cadc as _core
 from repro.kernels import cadc_conv as _ck
+from repro.kernels import cadc_gmm as _gk
 from repro.kernels import cadc_matmul as _pk
 
 Array = jnp.ndarray
@@ -113,6 +114,36 @@ def cadc_matmul_q8(
         save_gate=save_gate,
         vmem_budget_bytes=vmem_budget_bytes,
     )
+
+
+def cadc_gmm(
+    x: Array,
+    w: Array,
+    group_sizes: Array,
+    *,
+    crossbar_size: int = 256,
+    fn: str = "relu",
+    block_m: int = 256,
+    impl: str = "auto",
+) -> Array:
+    """Grouped CADC matmul over the experts a chip holds: x [R, D] rows
+    sorted by expert, each group of `group_sizes` [E] starting on a
+    `block_m` row tile (`cadc_gmm.tile_layout`); w [E, S, xbar, N] (or
+    [E, D, N]). Returns [R, N] in x's dtype, zeros outside the groups'
+    tiles. "xla" is the kernel's oracle (psums materialised)."""
+    e, n = w.shape[0], w.shape[-1]
+    w3 = w.reshape(e, -1, n)
+    _, tile_expert, n_live = _gk.tile_layout(group_sizes, block_m,
+                                             x.shape[0] // block_m)
+    mode = _resolve(impl)
+    if mode == "xla":
+        return _gk.cadc_gmm_xla(x, w3, tile_expert, n_live,
+                                crossbar_size=crossbar_size, fn=fn,
+                                block_m=block_m)
+    return _gk.cadc_gmm_pallas(
+        x, w3, tile_expert, n_live, crossbar_size=crossbar_size, fn=fn,
+        block_m=block_m, block_n=256 if n % 256 == 0 else n,
+        interpret=(mode == "interpret"))
 
 
 def paged_attention(

@@ -167,15 +167,20 @@ def make_batched_prefill_step(cfg: ArchConfig) -> Callable:
     """Serving prefill: full-sequence forward over left-aligned ragged
     prompts. lengths [B] picks each slot's own last-token logits (per-slot
     position offsets for the subsequent decode steps = lengths). Returns
-    (next_tokens [B], last_logits [B, V], cache contributions) — the
-    contributions feed the serve engine's cache writers (dense or paged)."""
+    (next_tokens [B], last_logits [B, V], cache contributions, routed) —
+    the contributions feed the serve engine's cache writers (dense or
+    paged); `routed` is each MoE layer's routing, the rows routed to each
+    held expert and every row's selected experts (`transformer.
+    _routed_rows`; () without experts)."""
 
     def batched_prefill_step(params, batch, lengths):
-        logits, contribs = tf.forward_prefill(
-            cast_compute(params, cfg), batch, cfg, lengths=lengths)
+        logits, contribs, routed = tf.forward_prefill(
+            cast_compute(params, cfg), batch, cfg, lengths=lengths,
+            with_routed=True)
         idx = jnp.maximum(lengths - 1, 0)[:, None, None]
         last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
-        return jnp.argmax(last, -1).astype(jnp.int32), last, contribs
+        return (jnp.argmax(last, -1).astype(jnp.int32), last, contribs,
+                routed)
 
     return batched_prefill_step
 

@@ -45,12 +45,24 @@ def attn_init(key, cfg: ArchConfig) -> Dict:
     kq, kk, kv, ko = jax.random.split(key, 4)
     d, h, k_, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     b = cfg.attn_qkv_bias
-    return {
+    p = {
         "wq": ll.linear_init(kq, d, h * hd, cfg, bias=b),
         "wk": ll.linear_init(kk, d, k_ * hd, cfg, bias=b),
         "wv": ll.linear_init(kv, d, k_ * hd, cfg, bias=b),
         "wo": ll.linear_init(ko, h * hd, d, cfg),
     }
+    if cfg.qk_norm:
+        p["q_norm"] = ll.rmsnorm_init(hd)
+        p["k_norm"] = ll.rmsnorm_init(hd)
+    return p
+
+
+def _qk_norm(p, q: Array, k: Array, cfg: ArchConfig):
+    """QK-norm: RMSNorm over head_dim on q and k, before rotary."""
+    if not cfg.qk_norm:
+        return q, k
+    return (ll.rmsnorm_apply(p["q_norm"], q, cfg.norm_eps),
+            ll.rmsnorm_apply(p["k_norm"], k, cfg.norm_eps))
 
 
 def _hshard(t: Array, cfg: ArchConfig) -> Array:
@@ -66,6 +78,7 @@ def _qkv(p, x, cfg: ArchConfig, positions: Array):
     q = _hshard(ll.linear_apply(p["wq"], x, cfg).reshape(b, s, h, hd), cfg)
     k = _hshard(ll.linear_apply(p["wk"], x, cfg).reshape(b, s, k_, hd), cfg)
     v = _hshard(ll.linear_apply(p["wv"], x, cfg).reshape(b, s, k_, hd), cfg)
+    q, k = _qk_norm(p, q, k, cfg)
     q = ll.rope(q, positions, cfg.rope_theta)
     k = ll.rope(k, positions, cfg.rope_theta)
     return q, k, v
@@ -225,6 +238,7 @@ def _decode_qkv(p: Dict, x: Array, cfg: ArchConfig, position: Array):
     v_new = ll.linear_apply(p["wv"], x, cfg).reshape(b, s, k_, hd)
     pos = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (b,))
     qpos = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+    q, k_new = _qk_norm(p, q, k_new, cfg)
     q = ll.rope(q, qpos, cfg.rope_theta)
     k_new = ll.rope(k_new, qpos, cfg.rope_theta)
     return q, k_new, v_new, pos

@@ -40,7 +40,7 @@ def _causal_conv1d(p: Dict, x: Array) -> Array:
         xp[:, i : i + x.shape[1], :] * w[i]
         for i in range(width)
     )
-    return y + p["b"].astype(x.dtype)
+    return y + p["b"].astype(x.dtype) if "b" in p else y
 
 
 def _conv1d_step(p: Dict, buf: Array, x_t: Array) -> Tuple[Array, Array]:
@@ -48,7 +48,9 @@ def _conv1d_step(p: Dict, buf: Array, x_t: Array) -> Tuple[Array, Array]:
     w = p["w"].astype(x_t.dtype)
     width = w.shape[0]
     window = jnp.concatenate([buf, x_t[:, None, :]], axis=1)  # [B, width, C]
-    y = jnp.einsum("bwc,wc->bc", window, w) + p["b"].astype(x_t.dtype)
+    y = jnp.einsum("bwc,wc->bc", window, w)
+    if "b" in p:
+        y = y + p["b"].astype(x_t.dtype)
     return y, window[:, 1:]
 
 
@@ -343,8 +345,12 @@ def slstm_apply(p: Dict, x: Array, cfg: ArchConfig) -> Array:
 
 def slstm_init_state(cfg: ArchConfig, batch: int) -> SLSTMState:
     dh = cfg.d_model // cfg.n_heads
-    zeros = jnp.zeros((batch, cfg.n_heads, dh), jnp.float32)
-    return SLSTMState(zeros, zeros, jnp.full_like(zeros, -jnp.inf), zeros)
+    shape = (batch, cfg.n_heads, dh)
+    # one buffer a field: a cache pytree is donated to the decode program
+    return SLSTMState(jnp.zeros(shape, jnp.float32),
+                      jnp.zeros(shape, jnp.float32),
+                      jnp.full(shape, -jnp.inf, jnp.float32),
+                      jnp.zeros(shape, jnp.float32))
 
 
 def slstm_decode(p: Dict, x: Array, cfg: ArchConfig,

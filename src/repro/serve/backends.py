@@ -7,7 +7,11 @@ Both backends expose the same four jitted programs —
     reset_slots(caches, slot_mask) -> caches
     init_caches() -> caches
 
-— and the paged backend built with spec_tokens=K adds the speculative
+— `decode` also keeps its program's MoE routing in `routed` (the rows
+routed to each held expert and each token's selected experts in each MoE
+layer, `transformer._routed_rows`, or () without experts), for the engine
+to fetch with the tokens — and the paged
+backend built with spec_tokens=K adds the speculative
 draft/verify program
 
     decode_spec(params, caches, tables, tokens [B, K+1], positions)
@@ -57,11 +61,13 @@ Array = jnp.ndarray
 def map_layer_caches(caches, contribs, cfg: ArchConfig,
                      fn: Callable[[str, bool, Any, Any], Any]):
     """Apply fn(kind, stacked, cache_subtree, contrib_subtree) per layer
-    position of the {'units', 'tail'} cache pytree. contribs may be None
-    (fn then receives None)."""
+    position of the {'lead'?, 'units', 'tail'} cache pytree. contribs may
+    be None (fn then receives None)."""
     reps, pattern, tail = tf.layout(cfg)
+    lead = tf.lead_kinds(cfg)
     c_units = contribs["units"] if contribs is not None else None
     c_tail = contribs["tail"] if contribs is not None else None
+    c_lead = contribs.get("lead") if contribs is not None else None
     units = tuple(
         fn(pattern[j], True, caches["units"][j],
            c_units[j] if c_units is not None else None)
@@ -72,7 +78,13 @@ def map_layer_caches(caches, contribs, cfg: ArchConfig,
            c_tail[i] if c_tail is not None else None)
         for i in range(len(caches["tail"]))
     )
-    return {"units": units, "tail": tails}
+    out = {"units": units, "tail": tails}
+    if lead:
+        out["lead"] = tuple(
+            fn(kind, False, caches["lead"][i],
+               c_lead[i] if c_lead is not None else None)
+            for i, kind in enumerate(lead))
+    return out
 
 
 def _ring_vals(kv: Array, lengths: Array, ring_len: int
@@ -115,10 +127,13 @@ class _Backend:
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._write = jax.jit(self._write_impl, donate_argnums=(0,))
         self._reset = jax.jit(self._reset_impl, donate_argnums=(0,))
+        self.routed = ()
 
     # -- public jitted entry points ------------------------------------
     def decode(self, params, caches, tables, tokens, positions):
-        return self._decode(params, caches, tables, tokens, positions)
+        nxt, logits, caches, self.routed = self._decode(
+            params, caches, tables, tokens, positions)
+        return nxt, logits, caches
 
     def write_prefill(self, caches, contribs, slot_ids, lengths, tables):
         return self._write(caches, contribs, slot_ids, lengths, tables)
@@ -184,10 +199,11 @@ class DenseBackend(_Backend):
 
     def _decode_impl(self, params, caches, tables, tokens, positions):
         del tables
-        logits, caches = tf.decode_step(
+        logits, caches, routed = tf.decode_step(
             steps_lib.cast_compute(params, self.cfg), tokens, positions,
-            caches, self.cfg)
-        return jnp.argmax(logits, -1).astype(jnp.int32), logits, caches
+            caches, self.cfg, with_routed=True)
+        return (jnp.argmax(logits, -1).astype(jnp.int32), logits, caches,
+                routed)
 
     def _write_impl(self, caches, contribs, slot_ids, lengths, tables):
         del tables
@@ -297,10 +313,12 @@ class PagedBackend(_Backend):
         return out
 
     def _decode_impl(self, params, caches, tables, tokens, positions):
-        logits, caches = tf.decode_step_paged(
+        logits, caches, routed = tf.decode_step_paged(
             steps_lib.cast_compute(params, self.cfg), tokens, positions,
-            caches, tables, self.cfg, ring_lens=self.ring_len)
-        return jnp.argmax(logits, -1).astype(jnp.int32), logits, caches
+            caches, tables, self.cfg, ring_lens=self.ring_len,
+            with_routed=True)
+        return (jnp.argmax(logits, -1).astype(jnp.int32), logits, caches,
+                routed)
 
     # -- speculative draft/verify ---------------------------------------
     def decode_spec(self, params, caches, tables, tokens, positions):

@@ -110,6 +110,11 @@ class Request:
     patches: Optional[np.ndarray] = None
     tokens: List[int] = dataclasses.field(default_factory=list)
     logits: List[np.ndarray] = dataclasses.field(default_factory=list)
+    # models with experts: the experts each token fed through the model
+    # was routed to, [n_moe_layers, rows, top_k] a prefill or decode call
+    # (ids over all experts, in the router's order; not kept by the
+    # speculative draft/verify step)
+    routes: List[np.ndarray] = dataclasses.field(default_factory=list)
     done: bool = False
 
 
@@ -390,15 +395,24 @@ class ServeEngine:
             self.cfg, self.ecfg.n_slots, admitted)
 
         t0 = time.perf_counter()
-        first, last, contribs = self._prefill_fn(self.params, batch, lengths)
+        first, last, contribs, routed = self._prefill_fn(
+            self.params, batch, lengths)
         self.caches = self.backend.write_prefill(
             self.caches, contribs, slot_ids, lengths, self._device_tables())
-        first_np = np.asarray(first)
+        first_np, routed_np = jax.device_get((first, routed))
         last_np = np.asarray(last) if self.ecfg.record_logits else None
         self.telemetry.record_prefill(time.perf_counter() - t0)
+        self.telemetry.record_routed("prefill", routed_np)
+        top = None
+        if routed_np:
+            top = routed_np["top"]
+            top = top.reshape(top.shape[0], self.ecfg.n_slots, -1,
+                              top.shape[-1])
 
         now = self.telemetry.now()
         for i, (slot, req) in enumerate(admitted):
+            if top is not None:
+                req.routes.append(top[:, i, : req.prompt.size].copy())
             tok = int(first_np[i])
             req.tokens.append(tok)
             if last_np is not None:
@@ -443,14 +457,18 @@ class ServeEngine:
         nxt, logits, self.caches = self.backend.decode(
             self.params, self.caches, dev_tables,
             jnp.asarray(tokens), jnp.asarray(positions))
-        nxt_np = np.asarray(nxt)
+        nxt_np, routed_np = jax.device_get((nxt, self.backend.routed))
         logits_np = np.asarray(logits) if self.ecfg.record_logits else None
         dt = time.perf_counter() - t0
+        self.telemetry.record_routed("decode", routed_np)
+        top = routed_np["top"] if routed_np else None
 
         emitted = 0
         now = self.telemetry.now()
         for s in range(n):
             req = self.slot_req[s]
+            if top is not None and self.slot_phase[s] != IDLE:
+                req.routes.append(top[:, s : s + 1])
             if self.slot_phase[s] == DECODE:
                 tok = int(nxt_np[s])
                 req.tokens.append(tok)

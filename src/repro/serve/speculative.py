@@ -194,7 +194,7 @@ class DraftModelProposer(Proposer):
         # draft frontier mirrors the target's only while the layouts match
         batch, lengths, slot_ids = make_prefill_batch(self.cfg_d, n,
                                                       admitted)
-        _, _, contribs = self._prefill(self.params, batch, lengths)
+        _, _, contribs, _ = self._prefill(self.params, batch, lengths)
         self.caches = self.backend.write_prefill(
             self.caches, contribs, slot_ids, lengths, None)
         for slot, req in admitted:

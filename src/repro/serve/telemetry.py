@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -51,6 +51,9 @@ class Telemetry:
         self.spec_accepted = 0
         self.spec_committed = 0
         self.spec_slot_steps = 0  # sum of active-slot counts over steps
+        # rows routed to each held expert in each MoE layer, one entry a
+        # prefill or decode call: (kind, [n_moe_layers, E_here] int array)
+        self.routed: List[Tuple[str, np.ndarray]] = []
         self._t0 = time.perf_counter()
 
     def now(self) -> float:
@@ -68,6 +71,13 @@ class Telemetry:
 
     def record_prefill(self, dt: float) -> None:
         self.prefill_s.append(dt)
+
+    def record_routed(self, kind: str, routed) -> None:
+        """The routed-rows counter of the routing a prefill or decode
+        program returned (`transformer._routed_rows`), fetched with its
+        tokens; nothing for a model without experts."""
+        if routed:
+            self.routed.append((kind, np.asarray(routed["rows"])))
 
     def record_spec(self, drafted: int, accepted: int, committed: int,
                     n_active: int) -> None:

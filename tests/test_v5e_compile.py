@@ -135,6 +135,29 @@ def test_resnet18_classifier(one_chip, q8):
                                          impl="pallas"), x, w)
 
 
+# LFM2-8B-A1B's held experts (8 of 32, d 2048, expert width 1792, top-4):
+# the grouped kernel at a decode step of 16 and 32 slots and at prefill
+# waves of 8192 and 16384 rows, each row routed 4 ways, for the gate/up
+# and the down shapes; its custom call keeps the name the roofline reads
+GMM = [(rows * 4, d, n) for rows in (16, 32, 8192, 16384)
+       for d, n in ((2048, 1792), (1792, 2048))]
+
+
+@pytest.mark.parametrize("pairs,d,n", GMM)
+def test_cadc_gmm_lfm2(one_chip, pairs, d, n):
+    from repro.kernels import cadc_gmm
+
+    bm = cadc_gmm.block_m_for(pairs, 8)
+    rows = cadc_gmm.tile_bound(pairs, 8, bm) * bm
+    text = jax.jit(
+        lambda x, w, g: ops.cadc_gmm(x, w, g, crossbar_size=256,
+                                     block_m=bm, impl="pallas")
+    ).lower(_spec((rows, d), jnp.bfloat16, one_chip),
+            _spec((8, d // 256, 256, n), jnp.bfloat16, one_chip),
+            _spec((8,), jnp.int32, one_chip)).compile().as_text()
+    assert re.search(r"%cadc_gmm_pallas[.\d]* = .*custom-call", text)
+
+
 # ResNet-18 forward (width 64, crossbar 64, Pallas kernels) at batch 8: the
 # custom calls keep the names that the benchmark's roofline readers match,
 # and the forward's named scopes reach every instruction the chip runs
