@@ -126,9 +126,11 @@ class ArchConfig:
 
     # ---- numerics / execution ----
     dtype: str = "bfloat16"
-    # stored-parameter dtype. Training keeps fp32 masters (bf16_wire casts
-    # per step); SERVING stores bf16 — halves the per-token weight reads
-    # that dominate decode cells (§Perf iter 6).
+    # stored-parameter dtype of training and the dry run's abstract params.
+    # Training keeps fp32 masters (bf16_wire casts per step). The serve
+    # engine keeps its weights in the compute dtype whatever this says: it
+    # casts a float32 tree once when it is built (serve.backends.
+    # compute_weights).
     params_dtype: str = "float32"
     remat: bool = True
     attn_chunk: int = 512            # q-chunk for blockwise attention

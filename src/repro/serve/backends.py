@@ -114,6 +114,34 @@ def _mask_rows(mask: Array, like: Array, axis: int) -> Array:
 
 
 # ---------------------------------------------------------------------------
+# kept weights
+# ---------------------------------------------------------------------------
+
+def compute_weights(params, cfg: ArchConfig) -> Tuple[Any, int]:
+    """The weights in the dtype the serve programs compute with, and the
+    bytes cast to get there. `cast_compute` runs once, as one program on
+    the device, in place of once inside every step: the programs then
+    compute on the same bits, and their own `cast_compute` traces to
+    nothing. A tree it would leave alone (bf16_wire off, or no float32
+    leaf to narrow) is returned as given, with 0."""
+    narrows = cfg.bf16_wire and jnp.dtype(cfg.dtype) != jnp.float32
+    n = sum(a.nbytes for a in jax.tree_util.tree_leaves(params)
+            if narrows and a.dtype == jnp.float32)
+    if not n:
+        return params, 0
+    return jax.jit(functools.partial(steps_lib.cast_compute, cfg=cfg))(
+        params), n
+
+
+def weight_bytes(params) -> Dict[str, int]:
+    """Bytes of a weight tree by dtype name."""
+    out: Dict[str, int] = {}
+    for a in jax.tree_util.tree_leaves(params):
+        out[str(a.dtype)] = out.get(str(a.dtype), 0) + a.nbytes
+    return out
+
+
+# ---------------------------------------------------------------------------
 # shared backend skeleton
 # ---------------------------------------------------------------------------
 

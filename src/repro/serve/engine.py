@@ -158,7 +158,10 @@ class ServeEngine:
             raise ValueError("speculative decoding needs batched prefill")
         self.cfg = cfg
         self.ecfg = ecfg
-        self.params = params
+        # kept in the dtype the programs compute with: cast once here,
+        # not inside every prefill and decode call
+        self.params, self._cast_bytes = backends_lib.compute_weights(
+            params, cfg)
         self.telemetry_every = (cfg.serve_telemetry_every
                                 if ecfg.telemetry_every is None
                                 else ecfg.telemetry_every)
@@ -177,7 +180,7 @@ class ServeEngine:
             self.tables = BlockTables(
                 ecfg.n_slots, self.backend.blocks_per_slot,
                 self.backend.n_blocks)
-        self.telemetry = Telemetry()
+        self.telemetry = self._new_telemetry()
 
         n = ecfg.n_slots
         self.slot_req: List[Optional[Request]] = [None] * n
@@ -256,15 +259,22 @@ class ServeEngine:
         diagnostics — call between a warmup run (which compiles every
         jitted program) and the measured run, so percentiles and the
         slot/block-reuse gates reflect serving, not compilation. The
+        weight counters, fixed when the engine was built, carry over. The
         engine must be drained (no queued or active requests)."""
         if self.has_work():
             raise RuntimeError("reset_metrics on a non-drained engine")
-        self.telemetry = Telemetry()
+        self.telemetry = self._new_telemetry()
         self.results = {}
         self._it = 0
         self.slot_uses[:] = 0
         if self.tables is not None:
             self.tables.reset_stats()
+
+    def _new_telemetry(self) -> Telemetry:
+        tel = Telemetry()
+        tel.record_weights(self._cast_bytes,
+                           backends_lib.weight_bytes(self.params))
+        return tel
 
     def run(self, workload: Optional[Sequence[Tuple[int, np.ndarray, int]]]
             = None, *, max_steps: int = 100_000) -> Dict[str, Any]:

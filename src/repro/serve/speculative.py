@@ -137,7 +137,9 @@ class DraftModelProposer(Proposer):
                 f"draft vocab {self.cfg_d.vocab_size} != target vocab "
                 f"{cfg.vocab_size}: proposals would not be target tokens")
         self.n_slots = n_slots
-        self.params = tf.init(jax.random.PRNGKey(seed), self.cfg_d)
+        # kept in the compute dtype, as the engine keeps the target's
+        self.params, _ = backends_lib.compute_weights(
+            tf.init(jax.random.PRNGKey(seed), self.cfg_d), self.cfg_d)
         # + k: the draft rolls out past the committed frontier, and its
         # global rings must hold those positions without clip collisions
         self.backend = backends_lib.DenseBackend(self.cfg_d, n_slots,

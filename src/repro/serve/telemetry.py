@@ -54,6 +54,9 @@ class Telemetry:
         # rows routed to each held expert in each MoE layer, one entry a
         # prefill or decode call: (kind, [n_moe_layers, E_here] int array)
         self.routed: List[Tuple[str, np.ndarray]] = []
+        # the weights the engine keeps (record_weights)
+        self.weights_cast_bytes = 0
+        self.weights_bytes: Dict[str, int] = {}
         self._t0 = time.perf_counter()
 
     def now(self) -> float:
@@ -78,6 +81,15 @@ class Telemetry:
         tokens; nothing for a model without experts."""
         if routed:
             self.routed.append((kind, np.asarray(routed["rows"])))
+
+    def record_weights(self, cast_bytes: int,
+                       kept: Dict[str, int]) -> None:
+        """The bytes of float32 weights the engine cast to its compute
+        dtype when it was built, and the bytes of the weights it keeps, by
+        dtype. Kept float32 bytes with bf16_wire on mean every program
+        casts them again on every call."""
+        self.weights_cast_bytes = cast_bytes
+        self.weights_bytes = dict(kept)
 
     def record_spec(self, drafted: int, accepted: int, committed: int,
                     n_active: int) -> None:
@@ -121,6 +133,8 @@ class Telemetry:
             "ttft_ms_p99": _pct(ttfts, 99) * 1e3,
             "prefill_ms_p50": _pct(self.prefill_s, 50) * 1e3,
             "wall_s": time.perf_counter() - self._t0,
+            "weights_cast_bytes": self.weights_cast_bytes,
+            "weights_bytes": dict(self.weights_bytes),
         }
         if self.spec_steps:
             out["speculative"] = {

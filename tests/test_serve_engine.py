@@ -8,6 +8,7 @@ decode-mode engine reproduces the legacy fixed-batch serve_step loop
 exactly.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -139,6 +140,106 @@ class TestLegacyAnchor:
         for rid in fast.results:
             lf, ls = fast.results[rid].logits[0], slow.results[rid].logits[0]
             np.testing.assert_allclose(lf, ls, rtol=2e-4, atol=2e-4)
+
+
+    @pytest.mark.parametrize("arch", ["gemma3_1b", "lfm2_8b_a1b"])
+    def test_engine_given_fp32_matches_given_cast(self, arch):
+        """bf16 compute: an engine given float32 weights keeps them cast
+        once, and serves the same tokens and logits, bit for bit, as an
+        engine given the same weights already cast, and as programs that
+        cast the float32 tree inside every call."""
+        cfg, params = _setup(arch)
+        cfg = cfg.with_overrides(dtype="bfloat16")
+        wl = _staggered_workload(cfg)
+        given_fp32 = _run(cfg, params, "paged", wl)
+        given_cast = _run(cfg, steps_lib.cast_compute(params, cfg), "paged",
+                          wl)
+        in_step = ServeEngine(cfg, params, EngineConfig(
+            n_slots=2, max_len=32, block_size=16, record_logits=True))
+        in_step.params = params  # every program casts the fp32 tree
+        in_step.run([(a, p.copy(), g) for a, p, g in wl])
+        for other in (given_cast, in_step):
+            assert sorted(other.results) == sorted(given_fp32.results)
+            for rid, req in given_fp32.results.items():
+                assert other.results[rid].tokens == req.tokens
+                assert all(np.array_equal(a, b) for a, b in
+                           zip(other.results[rid].logits, req.logits))
+
+
+def _weight_converts(lowered_text: str, params) -> list:
+    """Shapes of the float32 converts in a lowered program whose operand
+    has the shape of a float32 weight leaf."""
+    shapes = {"x".join(map(str, a.shape))
+              for a in jax.tree_util.tree_leaves(params)
+              if a.dtype == jnp.float32}
+    found = re.findall(r"stablehlo\.convert [^\n]*\(tensor<([0-9x]*)xf32>\)",
+                       lowered_text)
+    return [s for s in found if s.rstrip("x") in shapes]
+
+
+class TestKeptWeights:
+    """The engine keeps its weights in the dtype its programs compute
+    with, cast once when it is built."""
+
+    @pytest.mark.parametrize("arch", ["gemma3_1b", "lfm2_8b_a1b"])
+    def test_bf16_wire_keeps_bf16(self, arch):
+        cfg, params = _setup(arch)
+        cfg = cfg.with_overrides(dtype="bfloat16")
+        eng = ServeEngine(cfg, params, EngineConfig(
+            n_slots=2, max_len=32, block_size=16))
+        for a in jax.tree_util.tree_leaves(eng.params):
+            if jnp.issubdtype(a.dtype, jnp.floating):
+                assert a.dtype == jnp.bfloat16
+        fp32 = sum(a.nbytes for a in jax.tree_util.tree_leaves(params)
+                   if a.dtype == jnp.float32)
+        summary = eng.run(_staggered_workload(cfg))
+        eng.reset_metrics()  # the counters outlive a reset
+        for s in (summary, eng.telemetry.summary()):
+            assert s["weights_cast_bytes"] == fp32
+            assert s["weights_bytes"].get("float32", 0) == 0
+            assert s["weights_bytes"]["bfloat16"] == fp32 // 2
+
+    @pytest.mark.parametrize("case", ["bf16_wire_off", "float32_compute",
+                                      "already_cast"])
+    def test_nothing_to_cast_keeps_tree(self, case):
+        """bf16_wire off, a float32 compute dtype, or a tree already in
+        the compute dtype: the engine keeps the tree as given."""
+        cfg, params = _setup("gemma3_1b")
+        cfg = cfg.with_overrides(dtype="bfloat16")
+        if case == "bf16_wire_off":
+            cfg = cfg.with_overrides(bf16_wire=False)
+        elif case == "float32_compute":
+            cfg = cfg.with_overrides(dtype="float32")
+        else:
+            params = steps_lib.cast_compute(params, cfg)
+        eng = ServeEngine(cfg, params, EngineConfig(
+            n_slots=2, max_len=32, block_size=16))
+        assert eng.params is params
+        s = eng.telemetry.summary()
+        assert s["weights_cast_bytes"] == 0
+        assert sum(s["weights_bytes"].values()) == sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(params))
+
+    @pytest.mark.parametrize("arch", ["gemma3_1b", "lfm2_8b_a1b"])
+    def test_programs_convert_no_weight(self, arch):
+        """Lowered with the kept tree, neither the decode nor the batched
+        prefill program converts a float32 weight; lowered with the
+        float32 tree, both do (the check sees a cast in the step)."""
+        cfg, params = _setup(arch)
+        cfg = cfg.with_overrides(dtype="bfloat16")
+        eng = ServeEngine(cfg, params, EngineConfig(
+            n_slots=2, max_len=32, block_size=16))
+        n = eng.ecfg.n_slots
+        tokens = jnp.zeros((n,), jnp.int32)
+        batch = {"tokens": jnp.zeros((n, 8), jnp.int32)}
+        lengths = jnp.full((n,), 8, jnp.int32)
+        for tree, casts in ((eng.params, False), (params, True)):
+            decode = eng.backend._decode.lower(
+                tree, eng.caches, eng._device_tables(), tokens,
+                tokens).as_text()
+            prefill = eng._prefill_fn.lower(tree, batch, lengths).as_text()
+            for text in (decode, prefill):
+                assert bool(_weight_converts(text, params)) == casts
 
 
 class TestScheduling:

@@ -16,6 +16,7 @@ run, and the ring-headroom fail-fasts.
 import functools
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -120,16 +121,24 @@ class TestSpeculativeParity:
         assert 0.0 <= sp["accept_rate"] <= 1.0
         assert 1.0 <= sp["tokens_per_step"] <= 3.0
 
-    @pytest.mark.parametrize("arch", ["gemma3_1b", "recurrentgemma_9b"])
-    def test_draft_model_proposer_parity(self, arch):
+    @pytest.mark.parametrize("arch,dtype", [
+        ("gemma3_1b", "float32"), ("recurrentgemma_9b", "float32"),
+        ("gemma3_1b", "bfloat16")],
+        ids=["gemma3_1b", "recurrentgemma_9b", "gemma3_1b-bf16"])
+    def test_draft_model_proposer_parity(self, arch, dtype):
         """The shrunk-config draft model proposer: same invariant (its
         own dense caches track the committed frontier; rollouts are
-        thrown away)."""
+        thrown away). It keeps its weights in the compute dtype, cast
+        once when it is built."""
         cfg, params = _setup(arch)
+        cfg = cfg.with_overrides(dtype=dtype)
         wl = _staggered_workload(cfg)
         base = _run(cfg, params, wl)
         spec = _run(cfg, params, wl, spec_tokens=3, spec_draft="model")
         _assert_stream_parity(spec, base)
+        for a in jax.tree_util.tree_leaves(spec.proposer.params):
+            if jnp.issubdtype(a.dtype, jnp.floating):
+                assert a.dtype == jnp.dtype(dtype)
 
     def test_zero_acceptance_degenerates_to_decode(self):
         """All drafts rejected => every verify step commits exactly one
